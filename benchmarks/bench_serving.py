@@ -45,7 +45,8 @@ def bench_serving(arch="qwen2_0_5b", session_counts=(1, 2, 4, 8),
                                    requests_per_session=requests,
                                    n_tokens=tokens, max_batch=max_batch,
                                    scheduler=sched, seed=42,
-                                   workload=workload, read_pct=read_pct)
+                                   workload=workload, read_pct=read_pct,
+                                   reduced=True)
 
             cell()                                    # warmup
             samples = sorted((cell() for _ in range(repeats)),

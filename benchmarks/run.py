@@ -16,9 +16,11 @@ import importlib
 import time
 
 from repro.core import substrate
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main(argv=None) -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--repeats", type=int, default=2,
                     help="timed repeats per bench row (median + IQR via "

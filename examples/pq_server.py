@@ -28,7 +28,7 @@ def main():
         stats = run_serving(a.arch, sessions=a.sessions,
                             requests_per_session=a.requests,
                             n_tokens=a.tokens, max_batch=a.max_batch,
-                            scheduler=sched, seed=0)
+                            scheduler=sched, seed=0, reduced=True)
         print(f"  {sched:8s}: {stats['req_per_s']:7.2f} req/s  "
               f"{stats['device_steps']:4d} device dispatches  "
               f"mean batch {stats['mean_batch']}")

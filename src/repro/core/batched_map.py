@@ -21,8 +21,9 @@ combining pass of mixed updates a **sort-merge**:
 * **vectorized batched reads** — ``lookup``, ``range_count``,
   ``range_sum`` (closed interval [lo, hi]) and ``kth_smallest`` are ONE
   fused program per read batch: masked binary search (``searchsorted``
-  against the sorted body), prefix sums for range aggregation, and a
-  shard-size cumsum for the global k-th — reads never mutate state, so
+  against the sorted body), fixed-order block sums over the rank
+  interval for range aggregation, and a shard-size cumsum for the global
+  k-th — reads never mutate state, so
   the read pass is never donated and a read-only workload never copies
   the map (the §5.1 read-dominated setting this structure targets).
 * **multi-round scan path** (DESIGN.md §12) — update batches wider than
@@ -58,7 +59,8 @@ import numpy as np
 from . import placement as _placement
 from . import substrate
 from repro.kernels.sorted_merge import (merge_compact_sharded,
-                                        merge_compact_xla)
+                                        merge_compact_xla,
+                                        require_pallas_fits)
 
 from .batched_pq import INF, _flush_subnormals
 from .faults import make_guard
@@ -297,6 +299,65 @@ apply_rounds_undonated = jax.jit(_rounds_impl, static_argnames=_STATIC)
 # ---------------------------------------------------------------------------
 # Fused vectorized read pass (never donated — reads copy nothing)
 # ---------------------------------------------------------------------------
+_SUM_BLOCK = 128     # slots per block of the range-sum tree
+
+
+def _ordered_sum(x: jax.Array) -> jax.Array:
+    """Sum over the last axis as a fixed tree of pairwise adds (zero-padded
+    to a power of two).  The TPU compiler picks a reduce's order per
+    program shape, so a ``jnp.sum`` gives the stacked and the mesh layout
+    different last bits; elementwise adds give the same bits in any
+    program."""
+    n = x.shape[-1]
+    p = 1 << max(n - 1, 0).bit_length()
+    if p != n:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, p - n)])
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        x = x[..., :h] + x[..., h:]
+    return x[..., 0]
+
+
+def _probe_shard(bk, bv, sz, qa, qb):
+    """One shard's read probes for a (q,) query batch: (found, lookup
+    value, closed-interval count, closed-interval value sum).
+
+    The range sum over slots [lo, hi) adds the partial first and last
+    128-slot blocks to the sums of the whole blocks between them, every
+    sum an :func:`_ordered_sum`.  A difference of f32 prefix sums is wrong
+    at deployment size: on a million-slot shard the running total reaches
+    ~2^14, where one f32 ulp is already above the sum's tolerance."""
+    cap = bk.shape[0] - 1
+    body = bk[:cap]
+    # masked binary search: the +inf padding keeps searchsorted exact
+    pos = jnp.searchsorted(body, qa, side="left").astype(jnp.int32)
+    pos_c = jnp.clip(pos, 0, cap - 1)
+    found = (pos < sz) & (body[pos_c] == qa)
+    lval = jnp.where(found, bv[pos_c], INF)
+    # closed-interval rank bounds
+    lo = jnp.minimum(pos, sz)
+    hi = jnp.minimum(jnp.searchsorted(body, qb, side="right"), sz)
+    cnt = jnp.maximum(hi - lo, 0).astype(jnp.int32)
+    # blocks past sz hold the +inf padding: hi <= sz never selects them
+    B = _SUM_BLOCK
+    nb = -(-cap // B)
+    blocks = jnp.pad(bv[:cap], (0, nb * B - cap)).reshape(nb, B)
+    blo, bhi = lo // B, hi // B
+    lane = jnp.arange(B)[None, :]
+    s_first = blo[:, None] * B + lane
+    s_last = bhi[:, None] * B + lane
+    first = jnp.where((s_first >= lo[:, None]) & (s_first < hi[:, None]),
+                      blocks[jnp.clip(blo, 0, nb - 1)], 0.0)
+    last = jnp.where((bhi > blo)[:, None] & (s_last < hi[:, None]),
+                     blocks[jnp.clip(bhi, 0, nb - 1)], 0.0)
+    b = jnp.arange(nb)[None, :]
+    between = jnp.where((b > blo[:, None]) & (b < bhi[:, None]),
+                        _ordered_sum(blocks)[None, :], 0.0)
+    rsum = (_ordered_sum(first) + _ordered_sum(between)) \
+        + _ordered_sum(last)
+    return found, lval, cnt, rsum
+
+
 def _read_impl(state: MapState, qa: jax.Array, qb: jax.Array,
                qkind: jax.Array,
                *, placement=None) -> Tuple[jax.Array, jax.Array]:
@@ -314,31 +375,14 @@ def _read_impl(state: MapState, qa: jax.Array, qb: jax.Array,
     cap = keys.shape[1] - 1
     qa = _flush_subnormals(qa.astype(jnp.float32))
     qb = _flush_subnormals(qb.astype(jnp.float32))
-
-    def per_shard(bk, bv, sz):
-        body = bk[:cap]
-        # masked binary search: the +inf padding keeps searchsorted exact
-        pos = jnp.searchsorted(body, qa, side="left").astype(jnp.int32)
-        pos_c = jnp.clip(pos, 0, cap - 1)
-        found = (pos < sz) & (body[pos_c] == qa)
-        lval = jnp.where(found, bv[pos_c], INF)
-        # closed-interval rank bounds
-        lo = jnp.minimum(jnp.searchsorted(body, qa, side="left"), sz)
-        hi = jnp.minimum(jnp.searchsorted(body, qb, side="right"), sz)
-        cnt = jnp.maximum(hi - lo, 0).astype(jnp.int32)
-        # prefix sums of the live values for range aggregation
-        live = jnp.where(jnp.arange(cap) < sz, bv[:cap], 0.0)
-        ps = jnp.concatenate([jnp.zeros((1,), jnp.float32),
-                              jnp.cumsum(live)])
-        rsum = jnp.where(hi > lo, ps[hi] - ps[lo], 0.0)
-        return found, lval, cnt, rsum
-
-    found, lval, cnt, rsum = jax.vmap(per_shard)(keys, vals, size)
+    found, lval, cnt, rsum = jax.vmap(
+        lambda bk, bv, sz: _probe_shard(bk, bv, sz, qa, qb))(keys, vals,
+                                                             size)
     any_found = jnp.any(found, axis=0)
     # exactly one shard can hold the key (routing) — masked min IS select
     look_val = jnp.min(jnp.where(found, lval, INF), axis=0)
     total_cnt = jnp.sum(cnt, axis=0).astype(jnp.float32)
-    total_sum = jnp.sum(rsum, axis=0)
+    total_sum = _ordered_sum(rsum.T)
 
     # global k-th: key-range routing keeps the shard concatenation
     # globally sorted, so a cumulative-size search finds the owner shard
@@ -429,7 +473,6 @@ mixed_pass_undonated = jax.jit(_mixed_impl, static_argnames=_STATIC)
 # float sums bit-identical.  merge_compact_sharded (the Pallas kernel)
 # assumes the whole stack in one address space: use_pallas composes with
 # StackedPlacement only (the wrapper refuses the combination).
-from jax.experimental.shard_map import shard_map as _shard_map
 from jax.sharding import PartitionSpec as _P
 
 
@@ -501,23 +544,9 @@ def _mesh_read_body(keys, vals, size, qa, qb, qkind,
     qa = _flush_subnormals(qa.astype(jnp.float32))
     qb = _flush_subnormals(qb.astype(jnp.float32))
     base = jax.lax.axis_index(axis) * K_local
-
-    def per_shard(bk, bv, sz):
-        body = bk[:cap]
-        pos = jnp.searchsorted(body, qa, side="left").astype(jnp.int32)
-        pos_c = jnp.clip(pos, 0, cap - 1)
-        found = (pos < sz) & (body[pos_c] == qa)
-        lval = jnp.where(found, bv[pos_c], INF)
-        lo = jnp.minimum(jnp.searchsorted(body, qa, side="left"), sz)
-        hi = jnp.minimum(jnp.searchsorted(body, qb, side="right"), sz)
-        cnt = jnp.maximum(hi - lo, 0).astype(jnp.int32)
-        live = jnp.where(jnp.arange(cap) < sz, bv[:cap], 0.0)
-        ps = jnp.concatenate([jnp.zeros((1,), jnp.float32),
-                              jnp.cumsum(live)])
-        rsum = jnp.where(hi > lo, ps[hi] - ps[lo], 0.0)
-        return found, lval, cnt, rsum
-
-    found_l, lval_l, cnt_l, rsum_l = jax.vmap(per_shard)(keys, vals, size)
+    found_l, lval_l, cnt_l, rsum_l = jax.vmap(
+        lambda bk, bv, sz: _probe_shard(bk, bv, sz, qa, qb))(keys, vals,
+                                                             size)
     q = qa.shape[0]
     found = jax.lax.all_gather(found_l, axis).reshape(K, q)
     lval = jax.lax.all_gather(lval_l, axis).reshape(K, q)
@@ -528,7 +557,7 @@ def _mesh_read_body(keys, vals, size, qa, qb, qkind,
     any_found = jnp.any(found, axis=0)
     look_val = jnp.min(jnp.where(found, lval, INF), axis=0)
     total_cnt = jnp.sum(cnt, axis=0).astype(jnp.float32)
-    total_sum = jnp.sum(rsum, axis=0)
+    total_sum = _ordered_sum(rsum.T)
 
     ccum = jnp.cumsum(size_g)
     kq = qa.astype(jnp.int32)
@@ -567,10 +596,10 @@ def _mesh_apply(state, op_keys, op_vals, op_code, nb,
         return _mesh_apply_body(keys, vals, size, rk, rv, rc, rnb,
                                 n_shards=K, key_range=key_range, axis=ax)
 
-    fn = _shard_map(body, mesh=placement.mesh,
-                    in_specs=st_specs + (_P(), _P(), _P(), _P()),
-                    out_specs=st_specs + (_P(),),
-                    check_rep=False)
+    fn = jax.shard_map(body, mesh=placement.mesh,
+                       in_specs=st_specs + (_P(), _P(), _P(), _P()),
+                       out_specs=st_specs + (_P(),),
+                       check_vma=False)
     keys, vals, size, ok = fn(state.keys, state.vals, state.size,
                               op_keys, op_vals, op_code, nb)
     return MapState(keys, vals, size), ok
@@ -594,10 +623,10 @@ def _mesh_rounds(state, op_keys, op_vals, op_code, nb,
             step, (keys, vals, size), (rks, rvs, rcs, rnbs))
         return keys, vals, size, oks
 
-    fn = _shard_map(body, mesh=placement.mesh,
-                    in_specs=st_specs + (_P(), _P(), _P(), _P()),
-                    out_specs=st_specs + (_P(),),
-                    check_rep=False)
+    fn = jax.shard_map(body, mesh=placement.mesh,
+                       in_specs=st_specs + (_P(), _P(), _P(), _P()),
+                       out_specs=st_specs + (_P(),),
+                       check_vma=False)
     keys, vals, size, oks = fn(state.keys, state.vals, state.size,
                                op_keys, op_vals, op_code, nb)
     return MapState(keys, vals, size), oks
@@ -611,10 +640,10 @@ def _mesh_read(state, qa, qb, qkind, *, placement):
         return _mesh_read_body(keys, vals, size, qa, qb, qkind,
                                n_shards=K, axis=ax)
 
-    fn = _shard_map(body, mesh=placement.mesh,
-                    in_specs=st_specs + (_P(), _P(), _P()),
-                    out_specs=(_P(), _P()),
-                    check_rep=False)
+    fn = jax.shard_map(body, mesh=placement.mesh,
+                       in_specs=st_specs + (_P(), _P(), _P()),
+                       out_specs=(_P(), _P()),
+                       check_vma=False)
     return fn(state.keys, state.vals, state.size, qa, qb, qkind)
 
 
@@ -648,10 +677,10 @@ def _mesh_mixed(state, tags, op_a, op_b, op_code, nb,
             step, (keys, vals, size), (tags, ras, rbs, rcs, rnbs))
         return keys, vals, size, res, ok
 
-    fn = _shard_map(body, mesh=placement.mesh,
-                    in_specs=st_specs + (_P(), _P(), _P(), _P(), _P()),
-                    out_specs=st_specs + (_P(), _P()),
-                    check_rep=False)
+    fn = jax.shard_map(body, mesh=placement.mesh,
+                       in_specs=st_specs + (_P(), _P(), _P(), _P(), _P()),
+                       out_specs=st_specs + (_P(), _P()),
+                       check_vma=False)
     keys, vals, size, res, ok = fn(state.keys, state.vals, state.size,
                                    tags, op_a, op_b, op_code, nb)
     return MapState(keys, vals, size), res, ok
@@ -885,6 +914,8 @@ class ShardedMap(substrate.BatchedStructure):
                 "use_pallas is not supported under MeshPlacement: the "
                 "grid=(K,) merge-compact kernel assumes the whole shard "
                 "stack in one device's address space (DESIGN.md §18)")
+        if self.use_pallas:
+            require_pallas_fits(self.capacity)
         self.key_range = ((float(key_range[0]), float(key_range[1]))
                           if key_range is not None else None)
         self.fault_plan = fault_plan

@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels._rows import require_heap_fits
+
 INF = jnp.float32(jnp.inf)
 _TINY = float(np.finfo(np.float32).tiny)        # smallest normal f32
 
@@ -441,20 +443,22 @@ def apply_batch_impl(state: HeapState, n_extract: jax.Array,
 
     # phase 4: remaining inserts, chunked at level boundaries
     if use_pallas:
+        from repro.kernels._rows import from_rows, to_rows
         from repro.kernels.heap_insert import insert_chunk_sharded as _ins_k
 
-        # pad the insert headroom once; re-padding inside the chunk loop
-        # would copy the whole heap max_depth times per pass
-        a = jnp.concatenate([a, jnp.full((c_max,), INF, a.dtype)])
+        # convert to the kernel's row layout with the insert headroom once;
+        # re-padding inside the chunk loop would copy the whole heap
+        # max_depth times per pass
+        a = to_rows(a[None], min_width=cap + c_max, min_rows=2)
 
         def ins_fn(ah, s, v, m):
-            out, ns = _ins_k(ah[None], jnp.reshape(s, (1,)), v[None],
+            out, ns = _ins_k(ah, jnp.reshape(s, (1,)), v[None],
                              jnp.reshape(m, (1,)), pre_padded=True)
-            return out[0], ns[0]
+            return out, ns[0]
 
         a, size = _phase4(a, size, rem, m_left, ins_fn,
                           c_max=c_max, max_depth=max_depth)
-        a = a[:cap]
+        a = from_rows(a, cap)[0]
     else:
         a, size = _phase4_xla(a, size, rem, m_left, c_max=c_max,
                               max_depth=max_depth)
@@ -759,6 +763,8 @@ class BatchedPriorityQueue:
         self.c_max = int(c_max)
         self.capacity = int(capacity)
         self.use_pallas = bool(use_pallas)
+        if self.use_pallas:
+            require_heap_fits(self.capacity)
         self.donate = bool(donate)
         self.state = heap_init(capacity, values)
 

@@ -33,7 +33,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.sorted_merge import merge_compact_sharded, merge_compact_xla
+from repro.kernels.sorted_merge import (merge_compact_sharded,
+                                        merge_compact_xla,
+                                        require_pallas_fits)
 
 from . import substrate
 from .batched_map import _pow2
@@ -313,6 +315,8 @@ class ShardedSketch(substrate.BatchedStructure):
         self.n_shards = int(n_shards)
         self.topk_max = int(topk_max)
         self.use_pallas = bool(use_pallas)
+        if self.use_pallas:
+            require_pallas_fits(self.capacity)
         self.donate = bool(donate)
         self.fault_plan = fault_plan
         self._guard = make_guard(fault_plan, guard)

@@ -32,7 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.label_prop import label_step, label_step_xla
+from repro.kernels.label_prop import (label_step, label_step_xla,
+                                      require_pallas_fits)
 
 from . import substrate
 from .batched_map import _pow2
@@ -217,6 +218,8 @@ class BatchedUnionFind(substrate.BatchedStructure):
         self.c_max = int(c_max)
         self.n_shards = int(n_shards)
         self.use_pallas = bool(use_pallas)
+        if self.use_pallas:
+            require_pallas_fits(self.n)
         self.donate = bool(donate)
         self.fault_plan = fault_plan
         self._guard = make_guard(fault_plan, guard)

@@ -51,7 +51,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.label_prop import connected_components, merge_labels
+from repro.kernels.label_prop import (connected_components, merge_labels,
+                                      require_pallas_fits)
 
 from . import placement as _placement
 from . import substrate
@@ -456,6 +457,9 @@ class DeviceGraph(substrate.BatchedStructure):
         Updates and the contracted-graph fast path stay replicated
         (``GraphState`` is flat — there is no K axis to place).  Not
         combinable with ``use_pallas``.
+      edges: optional initial edge list, (m, 2) vertex pairs — loaded in
+        bulk on the host (canonicalized, deduplicated, self-loops
+        dropped); the first read runs a full label rebuild.
 
     Interface-compatible with ``DynamicGraph`` (``insert``/``delete``/
     ``connected``/``read_batch``/``apply``) plus the batched entry points
@@ -473,7 +477,7 @@ class DeviceGraph(substrate.BatchedStructure):
     def __init__(self, n_vertices: int, *, edge_capacity: int = 4096,
                  c_max: int = 64, n_shards: int = 1,
                  use_pallas: bool = False, donate: bool = True,
-                 fault_plan=None, guard=None, placement=None):
+                 fault_plan=None, guard=None, placement=None, edges=None):
         if n_vertices < 1:
             raise ValueError("n_vertices must be >= 1")
         if c_max < 1:
@@ -493,21 +497,24 @@ class DeviceGraph(substrate.BatchedStructure):
                 "use_pallas is not supported under MeshPlacement: the "
                 "grid=(K,) label kernel assumes the whole vertex "
                 "partition in one device's address space (DESIGN.md §18)")
+        if self.use_pallas:
+            require_pallas_fits(self.n, self.capacity)
         pend_cap = 2 * self.c_max
+        eu, ev, valid = self._initial_edges(edges)
         # +1: the scratch slot for predicated scatters (see GraphState)
         self.state = GraphState(
-            eu=jnp.zeros((self.capacity + 1,), jnp.int32),
-            ev=jnp.zeros((self.capacity + 1,), jnp.int32),
-            valid=jnp.zeros((self.capacity + 1,), jnp.bool_),
+            eu=jnp.asarray(eu),
+            ev=jnp.asarray(ev),
+            valid=jnp.asarray(valid),
             labels=jnp.arange(self.n, dtype=jnp.int32),
             pend=jnp.zeros((2, pend_cap + 1), jnp.int32),
             n_pend=jnp.int32(0),
-            dirty_full=jnp.bool_(False),
+            dirty_full=jnp.bool_(bool(valid.any())),
             n_full=jnp.int32(0),
         )
         # live-edge-count mirror: exact after every resolved fetch; the
         # bound adds inserts whose result masks are still on device
-        self._n_edges = 0
+        self._n_edges = int(valid.sum())
         self._outstanding_ins = 0
         # elimination instrumentation (DESIGN.md §12): ops answered by the
         # host chain rule instead of a device lane
@@ -524,6 +531,26 @@ class DeviceGraph(substrate.BatchedStructure):
         self._e_bound = 1
         self.fault_plan = fault_plan
         self._guard = make_guard(fault_plan, guard)
+
+    def _initial_edges(self, edges):
+        """Host edge buffer (eu, ev, valid), each capacity+1 long, holding
+        the distinct non-loop edges of ``edges`` as (min, max) pairs."""
+        eu = np.zeros((self.capacity + 1,), np.int32)
+        ev = np.zeros((self.capacity + 1,), np.int32)
+        valid = np.zeros((self.capacity + 1,), np.bool_)
+        if edges is None or len(edges) == 0:
+            return eu, ev, valid
+        e = np.asarray(edges, np.int64).reshape(-1, 2)
+        if e.min() < 0 or e.max() >= self.n:
+            raise ValueError(f"edge endpoints must lie in [0, {self.n})")
+        u, v = e.min(axis=1), e.max(axis=1)
+        code = np.unique(u[u != v] * self.n + v[u != v])
+        m = code.size
+        if m > self.capacity:
+            raise ValueError(f"{m} distinct edges exceed edge_capacity "
+                             f"{self.capacity}")
+        eu[:m], ev[:m], valid[:m] = code // self.n, code % self.n, True
+        return eu, ev, valid
 
     # -- transactional dispatch (DESIGN.md §15) -------------------------------
     def _snapshot(self):
@@ -907,8 +934,9 @@ class DeviceGraph(substrate.BatchedStructure):
         """Host copy of the live edge set (test/debug; one fetch)."""
         eu, ev, valid = _host_fetch((self.state.eu, self.state.ev,
                                      self.state.valid))
-        return {(int(u), int(v))
-                for u, v, ok in zip(eu, ev, valid) if ok}
+        valid = np.asarray(valid)
+        return set(zip(np.asarray(eu)[valid].tolist(),
+                       np.asarray(ev)[valid].tolist()))
 
 
 # ---------------------------------------------------------------------------

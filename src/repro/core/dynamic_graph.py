@@ -99,12 +99,11 @@ class DynamicGraph:
             edges = list(self.edges)           # snapshot, pre-clear ordering
             m = max(1, len(edges))
             pad = 1 << (m - 1).bit_length()    # pow2 padding limits recompiles
-            eu = np.zeros((pad,), np.int32)
-            ev = np.zeros((pad,), np.int32)
-            for i, (a, b) in enumerate(edges):
-                eu[i], ev[i] = a, b            # padding = (0,0) self-loops
-            self._labels = _components(jnp.asarray(eu), jnp.asarray(ev),
-                                       n=self.n)
+            uv = np.zeros((pad, 2), np.int32)  # padding = (0,0) self-loops
+            if edges:
+                uv[: len(edges)] = edges
+            self._labels = _components(jnp.asarray(uv[:, 0]),
+                                       jnp.asarray(uv[:, 1]), n=self.n)
 
     def connected(self, u: int, v: int) -> bool:
         self._refresh()

@@ -12,7 +12,7 @@ leading axis lives:
 * :class:`MeshPlacement` — the K rows are split across the ``axis``
   dimension of a 1-D :class:`jax.sharding.Mesh` (``D`` devices, ``K %
   D == 0``, ``K/D`` rows per device) and the fused passes run as
-  :func:`~jax.experimental.shard_map.shard_map` bodies whose K-way
+  :func:`jax.shard_map` bodies whose K-way
   merges are collectives (``all_gather`` of per-shard frontiers,
   ``psum`` of sizes, ``pmin`` of label tables).
 

@@ -52,6 +52,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels._rows import require_heap_fits
+
 from . import batched_pq as _bpq
 from . import placement as _placement
 from . import substrate
@@ -242,12 +244,13 @@ def _sharded_apply_batch(
     if use_pallas:
         from repro.kernels.heap_insert import insert_chunk_sharded as _ins_k
         from repro.kernels.heap_sift import sift_wavefront_sharded as _sift_k
-        a3 = _sift_k(a2, size2, starts, active)
-        # pad the insert headroom ONCE and carry the padded stack through
-        # the loop (re-padding per chunk would copy the whole heap stack
-        # max_depth times — exactly the per-pass copy donation removes)
-        a3 = jnp.concatenate(
-            [a3, jnp.full((K, c_max), INF, a3.dtype)], axis=1)
+        from repro.kernels._rows import from_rows, to_rows
+        # convert to the kernel's row layout with the insert headroom ONCE
+        # and carry it through the loop (re-padding per chunk would copy
+        # the whole heap stack max_depth times — exactly the per-pass copy
+        # donation removes)
+        a3 = to_rows(_sift_k(a2, size2, starts, active),
+                     min_width=cap + c_max, min_rows=2)
 
         # K-vector twin of batched_pq._phase4's chunk loop — the level-
         # boundary math is the shared elementwise _chunk_len
@@ -263,7 +266,7 @@ def _sharded_apply_batch(
         zeros = jnp.zeros((K,), jnp.int32)
         new_a, new_size, _, _ = jax.lax.fori_loop(
             0, max_depth + 1, chunk, (a3, size2, zeros, m_left))
-        new_a = new_a[:, :cap]
+        new_a = from_rows(new_a, cap)
     else:
         a3 = jax.vmap(_sift_wavefront)(a2, size2, starts, active)
         new_a, new_size = jax.vmap(
@@ -432,7 +435,6 @@ sharded_mixed_rounds_undonated = jax.jit(_sharded_mixed_impl,
 # The Pallas shard-grid kernels assume the whole (K, capacity) stack in
 # one address space, so use_pallas composes with StackedPlacement only
 # (the wrapper refuses the combination at construction).
-from jax.experimental.shard_map import shard_map as _shard_map
 from jax.sharding import PartitionSpec as _P
 
 
@@ -519,10 +521,10 @@ def _mesh_apply_batch(state, n_extract, insert_vals, n_insert,
                                 n_shards=n_shards, key_range=key_range,
                                 axis=ax)
 
-    fn = _shard_map(body, mesh=placement.mesh,
-                    in_specs=st_specs + (_P(), _P(), _P()),
-                    out_specs=st_specs + (_P(), _P()),
-                    check_rep=False)
+    fn = jax.shard_map(body, mesh=placement.mesh,
+                       in_specs=st_specs + (_P(), _P(), _P()),
+                       out_specs=st_specs + (_P(), _P()),
+                       check_vma=False)
     new_a, new_size, merged, k_eff = fn(
         state.a, state.size, n_extract, insert_vals, n_insert)
     return ShardedHeapState(new_a, new_size), merged, k_eff
@@ -545,10 +547,10 @@ def _mesh_rounds(state, n_extracts, insert_rows, n_inserts,
             step, (a, size), (ne_arr, bufs, ni_arr))
         return a, size, outs, k_effs
 
-    fn = _shard_map(body, mesh=placement.mesh,
-                    in_specs=st_specs + (_P(), _P(), _P()),
-                    out_specs=st_specs + (_P(), _P()),
-                    check_rep=False)
+    fn = jax.shard_map(body, mesh=placement.mesh,
+                       in_specs=st_specs + (_P(), _P(), _P()),
+                       out_specs=st_specs + (_P(), _P()),
+                       check_vma=False)
     a, size, outs, k_effs = fn(
         state.a, state.size, n_extracts, insert_rows, n_inserts)
     return ShardedHeapState(a, size), outs, k_effs
@@ -584,10 +586,10 @@ def _mesh_mixed(state, tags, n_extracts, insert_rows, n_inserts,
             step, (a, size), (tags, ne_arr, bufs, ni_arr))
         return a, size, outs, k_effs
 
-    fn = _shard_map(body, mesh=placement.mesh,
-                    in_specs=st_specs + (_P(), _P(), _P(), _P()),
-                    out_specs=st_specs + (_P(), _P()),
-                    check_rep=False)
+    fn = jax.shard_map(body, mesh=placement.mesh,
+                       in_specs=st_specs + (_P(), _P(), _P(), _P()),
+                       out_specs=st_specs + (_P(), _P()),
+                       check_vma=False)
     a, size, outs, k_effs = fn(
         state.a, state.size, tags, n_extracts, insert_rows, n_inserts)
     return ShardedHeapState(a, size), outs, k_effs
@@ -709,6 +711,8 @@ class ShardedBatchedPQ(substrate.BatchedStructure):
                 "use_pallas is not supported under MeshPlacement: the "
                 "shard-grid kernels assume the whole (K, capacity) stack "
                 "in one device's address space (DESIGN.md §18)")
+        if self.use_pallas:
+            require_heap_fits(self.capacity)
         self.key_range = (
             (float(key_range[0]), float(key_range[1]))
             if key_range is not None else None)

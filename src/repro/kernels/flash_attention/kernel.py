@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 NEG_INF = -1e30  # python float: jnp scalars would be captured consts in Pallas
 
@@ -150,7 +149,7 @@ def flash_attention_bhsd(
             pltpu.VMEM((block_q,), jnp.float32),        # l — running denom
             pltpu.VMEM((block_q, hd_v), jnp.float32),   # acc — weighted V sum
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

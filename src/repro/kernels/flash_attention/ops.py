@@ -12,11 +12,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
+
 from .kernel import flash_attention_bhsd
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(
@@ -39,7 +37,7 @@ def flash_attention(
 ) -> jax.Array:
     """Flash attention in model layout (B, S, heads, hd) → (B, Sq, H, hd_v)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = default_interpret()
     B, Sq, H, hd = q.shape
     _, Skv, K, _ = k.shape
     hd_v = v.shape[-1]

@@ -8,11 +8,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import _rows, default_interpret
+
 from .kernel import insert_sharded_vmem
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -44,23 +42,20 @@ def insert_chunk_sharded(a: jax.Array, size: jax.Array,
     per shard, all targets size_k+1..size_k+m_k lie on one tree level.
     Returns (new_a (K, cap), new_size (K,)).
 
-    ``pre_padded=True``: the caller already appended ≥ C slots of +inf
-    headroom (the kernel streams one contiguous C-wide level block) and
-    wants the padded array back — lets a chunk LOOP pad once instead of
-    re-concatenating + re-slicing the whole heap stack every iteration.
+    ``pre_padded=True``: ``a`` is already the kernel's ``(K, R, 128)``
+    row layout (``_rows.to_rows``) with ≥ C slots of +inf headroom past
+    every shard's size (the kernel streams one contiguous C-wide level
+    window), and the result comes back in it — lets a chunk LOOP convert
+    once instead of re-padding the whole heap stack every iteration.
     """
     if interpret is None:
-        interpret = not _on_tpu()
-    K, cap = a.shape
-    _, C = chunk_vals.shape
-    if pre_padded:
-        a_p, out_width = a, cap
-    else:
-        a_p = jnp.concatenate(
-            [a, jnp.full((K, C), jnp.inf, a.dtype)], axis=1)
-        out_width = cap                       # strip the headroom again
-        cap = cap + C
-    max_depth = int(math.ceil(math.log2(cap))) + 1
-    out = insert_sharded_vmem(a_p, size, chunk_vals, m_chunk,
+        interpret = default_interpret()
+    C = chunk_vals.shape[1]
+    a3 = a if pre_padded else _rows.to_rows(a, min_width=a.shape[1] + C,
+                                            min_rows=2)
+    max_depth = int(math.ceil(math.log2(a3.shape[1] * _rows.LANES))) + 1
+    out = insert_sharded_vmem(a3, size, chunk_vals, m_chunk,
                               max_depth=max_depth, interpret=interpret)
-    return out[:, :out_width], size + m_chunk
+    if not pre_padded:
+        out = _rows.from_rows(out, a.shape[1])
+    return out, size + m_chunk

@@ -7,11 +7,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import _rows, default_interpret
+
 from .kernel import kmin_sharded_vmem
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("c_max", "interpret"))
@@ -39,6 +37,6 @@ def k_smallest_sharded(a: jax.Array, size: jax.Array, n_extract: jax.Array,
     (ids (K, c_max) int32, vals (K, c_max) f32).
     """
     if interpret is None:
-        interpret = not _on_tpu()
-    return kmin_sharded_vmem(a, size, n_extract, c_max=c_max,
-                             interpret=interpret)
+        interpret = default_interpret()
+    return kmin_sharded_vmem(_rows.to_rows(a), size, n_extract,
+                             c_max=c_max, interpret=interpret)

@@ -14,17 +14,18 @@ equals the paper's sequential execution SE (deepest-first).
 Kernel layout (DESIGN.md §10 — the shard-grid revision):
 
 * the kernel runs over ``grid=(K,)`` — one program per heap shard of the
-  K-sharded queue (``sharded_pq.py``).  The ``(K, cap)`` heap is
-  block-sliced so each program sees only its own shard's ``(cap,)`` prefix
-  in VMEM (f32 capacity ≤ ~2M per shard is 8 MiB — within the 16 MiB VMEM
-  of a v5e core; the per-shard capacity of the K-sharded queue divides the
-  budget by K, see DESIGN.md §10).  ``K=1`` recovers the single-heap kernel
-  and is what ``BatchedPriorityQueue`` uses via the ops wrapper.
+  K-sharded queue (``sharded_pq.py``).  The heap stack is held in the
+  ``(K, cap/128, 128)`` row layout (``kernels/_rows.py``), block-sliced so
+  each program sees only its own shard's ``(cap/128, 128)`` block in VMEM;
+  the input and output blocks are double-buffered, which bounds the
+  per-shard capacity (``_rows.MAX_HEAP_CAPACITY``).  ``K=1`` recovers the
+  single-heap kernel and is what ``BatchedPriorityQueue`` uses via the ops
+  wrapper.
 * per-shard ``size`` / ``starts`` / ``active`` live in SMEM, indexed by
   ``pl.program_id(0)`` (scalar-unit reads).
-* cursor state (pos, active) is a register-resident ``(c,)`` vector carried
-  through the ``lax.while_loop``; each step does ≤ 3 scalar VMEM loads and
-  2 scalar VMEM stores per cursor (scalar-unit work — the paper's phase is
+* cursor state (pos, active, delay) lives in SMEM scratch; each step does
+  two row loads and two row read-modify-writes per cursor (the children of
+  a node share one row) — scalar-unit work: the paper's phase is
   latency- not throughput-bound, and fusing the whole wavefront in one
   kernel removes the per-level host round-trip of the pure-XLA version).
 * conditional stores write to slot 0 when inactive: the heap is 1-indexed
@@ -40,78 +41,80 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
+from repro.kernels import _rows
 
 INF = jnp.inf
 
 
-def _depth(v):
-    return 31 - jax.lax.clz(jnp.maximum(v, 1).astype(jnp.int32))
-
-
 def _sift_kernel(size_ref, starts_ref, active_ref, a_ref, out_ref,
-                 *, c: int, cap: int):
-    # one program per shard: scalars are rows of the (K, ...) SMEM inputs
+                 pos_s, act_s, delay_s, *, c: int, cap: int):
+    # one program per shard: scalars are rows of the (K, ...) SMEM inputs;
+    # the cursor state lives in SMEM scratch (scalar-unit reads/writes)
     shard = pl.program_id(0)
     # copy the shard's heap block into the output buffer, then mutate in place
     out_ref[...] = a_ref[...]
     size = size_ref[shard]
 
-    starts = starts_ref[shard, :]
-    active0 = active_ref[shard, :] != 0
+    def init(i, carry):
+        d_max, n_act = carry
+        st, ac = starts_ref[shard, i], active_ref[shard, i] != 0
+        pos_s[i] = st
+        act_s[i] = ac.astype(jnp.int32)
+        return (jnp.maximum(d_max, jnp.where(ac, _rows.depth(st), 0)),
+                n_act + ac.astype(jnp.int32))
 
-    depths = _depth(starts)
-    d_max = jnp.max(jnp.where(active0, depths, 0))
-    delay = d_max - depths
+    d_max, n_act = jax.lax.fori_loop(0, c, init, (jnp.int32(0), jnp.int32(0)))
 
-    def load1(idx):
-        return pl.load(out_ref, (pl.dslice(idx, 1),))[0]
+    def init_delay(i, _):
+        delay_s[i] = d_max - _rows.depth(pos_s[i])
+        return 0
 
-    def store1(idx, val):
-        pl.store(out_ref, (pl.dslice(idx, 1),),
-                 jnp.full((1,), val, out_ref.dtype))
+    jax.lax.fori_loop(0, c, init_delay, 0)
 
     def cursor(i, carry):
-        step, pos, active = carry
-        v = pos[i]
-        moving = active[i] & (step >= delay[i])
+        step, n_act = carry
+        v = pos_s[i]
+        moving = (act_s[i] != 0) & (step >= delay_s[i])
         vc = jnp.where(moving, v, 0)
         l, r = 2 * vc, 2 * vc + 1
-        av = load1(vc)
-        lv = jnp.where(moving & (l <= size) & (l < cap),
-                       load1(jnp.minimum(l, cap - 1)), INF)
-        rv = jnp.where(moving & (r <= size) & (r < cap),
-                       load1(jnp.minimum(r, cap - 1)), INF)
+        av = _rows.load1(out_ref, vc)
+        lraw, rraw = _rows.load_pair(out_ref, jnp.minimum(l, cap - 2))
+        lv = jnp.where(moving & (l <= size) & (l < cap), lraw, INF)
+        rv = jnp.where(moving & (r <= size) & (r < cap), rraw, INF)
         wv = jnp.minimum(lv, rv)
         w = jnp.where(lv <= rv, l, r)
         swap = moving & (wv < av)
         # predicated swap through the a[0] = +inf scratch slot
-        store1(jnp.where(swap, vc, 0), jnp.where(swap, wv, INF))
-        store1(jnp.where(swap, w, 0), jnp.where(swap, av, INF))
-        pos = jnp.where(jnp.arange(c) == i, jnp.where(swap, w, v), pos)
+        _rows.store1(out_ref, jnp.where(swap, vc, 0),
+                     jnp.where(swap, wv, INF))
+        _rows.store1(out_ref, jnp.where(swap, w, 0),
+                     jnp.where(swap, av, INF))
+        pos_s[i] = jnp.where(swap, w, v)
         stop = moving & ~swap
-        active = active & ~(jnp.arange(c) == i) | (
-            (jnp.arange(c) == i) & active & ~stop)
-        return step, pos, active
+        act_s[i] = jnp.where(stop, 0, act_s[i])
+        return step, n_act - stop.astype(jnp.int32)
 
     def body(carry):
-        step, pos, active = carry
-        _, pos, active = jax.lax.fori_loop(0, c, cursor, (step, pos, active))
-        return step + 1, pos, active
+        step, n_act = carry
+        _, n_act = jax.lax.fori_loop(0, c, cursor, (step, n_act))
+        return step + 1, n_act
 
     def cond(carry):
-        return jnp.any(carry[2])
+        return carry[1] > 0
 
-    jax.lax.while_loop(cond, body, (jnp.int32(0), starts, active0))
+    jax.lax.while_loop(cond, body, (jnp.int32(0), n_act))
 
 
 def sift_sharded_vmem(a: jax.Array, size: jax.Array, starts: jax.Array,
                       active: jax.Array, *, interpret: bool = False):
-    """a: (K, cap) f32 (1-indexed heaps, a[k, 0]=+inf); size: (K,) int32;
-    starts/active: (K, c) int32.  One grid program per shard."""
-    K, cap = a.shape
+    """a: (K, R, 128) f32 1-indexed heaps in the row layout (``_rows``),
+    a[k, 0]=+inf; size: (K,) int32; starts/active: (K, c) int32.  One
+    grid program per shard."""
+    K, R, _ = a.shape
     _, c = starts.shape
-    kernel = functools.partial(_sift_kernel, c=c, cap=cap)
+    kernel = functools.partial(_sift_kernel, c=c, cap=R * _rows.LANES)
+    heap = pl.BlockSpec((None, R, _rows.LANES), lambda k: (k, 0, 0),
+                        memory_space=pltpu.VMEM)
     return pl.pallas_call(
         kernel,
         grid=(K,),
@@ -119,13 +122,12 @@ def sift_sharded_vmem(a: jax.Array, size: jax.Array, starts: jax.Array,
             pl.BlockSpec(memory_space=pltpu.SMEM),   # size (K,)
             pl.BlockSpec(memory_space=pltpu.SMEM),   # starts (K, c)
             pl.BlockSpec(memory_space=pltpu.SMEM),   # active (K, c)
-            pl.BlockSpec((None, cap), lambda k: (k, 0),
-                         memory_space=pltpu.VMEM),   # heap shard
+            heap,                                    # heap shard
         ],
-        out_specs=pl.BlockSpec((None, cap), lambda k: (k, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((K, cap), a.dtype),
-        compiler_params=_compat.CompilerParams(
+        out_specs=heap,
+        out_shape=jax.ShapeDtypeStruct((K, R, _rows.LANES), a.dtype),
+        scratch_shapes=[pltpu.SMEM((c,), jnp.int32)] * 3,
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=False),
         interpret=interpret,
     )(size.astype(jnp.int32), starts.astype(jnp.int32),

@@ -7,11 +7,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import _rows, default_interpret
+
 from .kernel import sift_sharded_vmem
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -24,11 +22,9 @@ def sift_wavefront(a: jax.Array, size: jax.Array, starts: jax.Array,
     size: () int32; starts: (c,) int32 node ids; active: (c,) bool.
     Returns the updated heap array.  (K=1 shard-grid dispatch.)
     """
-    if interpret is None:
-        interpret = not _on_tpu()
-    out = sift_sharded_vmem(a[None], jnp.reshape(size, (1,)),
-                            starts[None], active[None], interpret=interpret)
-    return out[0]
+    return sift_wavefront_sharded(a[None], jnp.reshape(size, (1,)),
+                                  starts[None], active[None],
+                                  interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -41,5 +37,7 @@ def sift_wavefront_sharded(a: jax.Array, size: jax.Array, starts: jax.Array,
     starts/active: (K, c).  Returns the updated (K, cap) heap stack.
     """
     if interpret is None:
-        interpret = not _on_tpu()
-    return sift_sharded_vmem(a, size, starts, active, interpret=interpret)
+        interpret = default_interpret()
+    out = sift_sharded_vmem(_rows.to_rows(a), size, starts, active,
+                            interpret=interpret)
+    return _rows.from_rows(out, a.shape[1])

@@ -3,4 +3,5 @@ from .ops import (  # noqa: F401
     label_step,
     label_step_xla,
     merge_labels,
+    require_pallas_fits,
 )

@@ -48,50 +48,62 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
+from repro.kernels._rows import col_to_row
 
 # larger than any vertex id (labels live in [0, n_pad) with n_pad < 2^30);
 # a plain Python int so the kernel closes over no traced constants
 BIG = 1 << 30
+_V_TILE = 128       # lanes per vertex tile: block and chunk alignment
 
 
-def _gather_i32(table: jax.Array, idx: jax.Array, n_pad: int) -> jax.Array:
-    """table[idx] for i32 tables via broadcast-compare masked min.
+def _gather_i32(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """table[idx] for a (1, n_pad) i32 row and an (E, 1) index column,
+    via broadcast-compare masked min → (E, 1).
 
     ``idx`` values must lie in [0, n_pad).  Exactly one lane of the
-    ``(len(idx), n_pad)`` mask hits per row, so the row-min IS the gather —
-    no data-dependent addressing (TPU-portable, see module docstring).
+    ``(E, n_pad)`` mask hits per row, so the row-min IS the gather — no
+    data-dependent addressing (TPU-portable, see module docstring).
     """
-    vrow = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], n_pad), 1)
-    return jnp.min(jnp.where(idx[:, None] == vrow, table[None, :], BIG),
-                   axis=1)
+    vrow = jax.lax.broadcasted_iota(jnp.int32, table.shape, 1)
+    return jnp.min(jnp.where(idx == vrow, table, BIG), axis=1, keepdims=True)
+
+
+def _row_to_col(x: jax.Array) -> jax.Array:
+    """(1, W) row -> (W, 1) column through one sublane-tile transpose."""
+    return jnp.broadcast_to(x, (8, x.shape[1])).T[:, 0:1]
 
 
 def _label_step_kernel(labels_ref, eu_ref, ev_ref, out_ref,
-                       *, block: int, n_pad: int, e_cap: int, e_chunk: int):
+                       *, block: int, e_cap: int, e_chunk: int):
     k = pl.program_id(0)
-    base = k * block
-    labels = labels_ref[...]                       # (n_pad,) i32, full array
-    own = base + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)[:, 0]
-    s = labels_ref[pl.ds(base, block)]             # owned block of l
+    base = pl.multiple_of(k * block, _V_TILE)
+    labels = labels_ref[...]                       # (1, n_pad) i32, full row
+    own = base + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    s = labels_ref[:, pl.ds(base, block)]          # owned block of l
 
     def chunk(c, s):
-        off = c * e_chunk
-        eu = eu_ref[pl.ds(off, e_chunk)]           # (EC,) i32
-        ev = ev_ref[pl.ds(off, e_chunk)]
-        m = jnp.minimum(_gather_i32(labels, eu, n_pad),
-                        _gather_i32(labels, ev, n_pad))
-        # scatter-min of m into the owned vertex block (masked row-min;
+        off = pl.multiple_of(c * e_chunk, e_chunk)
+        eu = _row_to_col(eu_ref[:, pl.ds(off, e_chunk)])   # (EC, 1) i32
+        ev = _row_to_col(ev_ref[:, pl.ds(off, e_chunk)])
+        m = jnp.minimum(_gather_i32(labels, eu), _gather_i32(labels, ev))
+        # scatter-min of m into the owned vertex block (masked column-min;
         # padding edges are (0,0) self-loops — a no-op contribution)
-        cu = jnp.min(jnp.where(eu[None, :] == own[:, None],
-                               m[None, :], BIG), axis=1)
-        cv = jnp.min(jnp.where(ev[None, :] == own[:, None],
-                               m[None, :], BIG), axis=1)
+        cu = jnp.min(jnp.where(eu == own, m, BIG), axis=0, keepdims=True)
+        cv = jnp.min(jnp.where(ev == own, m, BIG), axis=0, keepdims=True)
         return jnp.minimum(s, jnp.minimum(cu, cv))
 
-    s = jax.lax.fori_loop(0, e_cap // e_chunk, chunk, s)
-    # pointer jump through the OLD labels (see module docstring)
-    out_ref[...] = jnp.minimum(s, _gather_i32(labels, s, n_pad))
+    out_ref[...] = jax.lax.fori_loop(0, e_cap // e_chunk, chunk, s)
+
+    # pointer jump through the OLD labels (see module docstring), one
+    # 128-vertex tile at a time to bound the gather mask
+    def jump(j, _):
+        o = pl.multiple_of(j * _V_TILE, _V_TILE)
+        sj = out_ref[:, pl.ds(o, _V_TILE)]
+        g = col_to_row(_gather_i32(labels, _row_to_col(sj)))
+        out_ref[:, pl.ds(o, _V_TILE)] = jnp.minimum(sj, g)
+        return 0
+
+    jax.lax.fori_loop(0, block // _V_TILE, jump, 0)
 
 
 def label_step_sharded_vmem(labels: jax.Array, eu: jax.Array, ev: jax.Array,
@@ -99,17 +111,20 @@ def label_step_sharded_vmem(labels: jax.Array, eu: jax.Array, ev: jax.Array,
                             interpret: bool = False) -> jax.Array:
     """One scatter-min + pointer-jump iteration as ONE ``grid=(K,)`` kernel.
 
-    labels: (n_pad,) i32 with n_pad divisible by ``n_shards``;
-    eu/ev: (e_cap,) i32 edge endpoints, (0, 0)-padded, e_cap divisible by
-    ``e_chunk``.  Returns the next label array, (n_pad,) i32.
+    labels: (n_pad,) i32 with n_pad divisible by ``n_shards`` into blocks
+    of a multiple of 128 vertices; eu/ev: (e_cap,) i32 edge endpoints,
+    (0, 0)-padded, e_cap divisible by ``e_chunk`` (a multiple of 128).
+    Returns the next label array, (n_pad,) i32.  All three arrays enter
+    as (1, ·) lane rows, so every dynamic slice is lane-tile aligned.
     """
     (n_pad,) = labels.shape
     (e_cap,) = eu.shape
     assert n_pad % n_shards == 0 and e_cap % e_chunk == 0
     block = n_pad // n_shards
-    kernel = functools.partial(_label_step_kernel, block=block, n_pad=n_pad,
+    assert block % _V_TILE == 0 and e_chunk % _V_TILE == 0
+    kernel = functools.partial(_label_step_kernel, block=block,
                                e_cap=e_cap, e_chunk=e_chunk)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(n_shards,),
         in_specs=[
@@ -117,9 +132,11 @@ def label_step_sharded_vmem(labels: jax.Array, eu: jax.Array, ev: jax.Array,
             pl.BlockSpec(memory_space=pltpu.VMEM),   # eu (full)
             pl.BlockSpec(memory_space=pltpu.VMEM),   # ev (full)
         ],
-        out_specs=pl.BlockSpec((block,), lambda k: (k,),
+        out_specs=pl.BlockSpec((1, block), lambda k: (0, k),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-        compiler_params=_compat.CompilerParams(has_side_effects=False),
+        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
+        compiler_params=pltpu.CompilerParams(has_side_effects=False),
         interpret=interpret,
-    )(labels.astype(jnp.int32), eu.astype(jnp.int32), ev.astype(jnp.int32))
+    )(labels.astype(jnp.int32)[None], eu.astype(jnp.int32)[None],
+      ev.astype(jnp.int32)[None])
+    return out[0]

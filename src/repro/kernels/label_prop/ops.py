@@ -27,18 +27,26 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
+from repro.kernels._rows import ceil_to
+
 from .kernel import label_step_sharded_vmem
 
-_E_CHUNK = 256      # edge streaming chunk (VMEM tile rows per iteration)
-_V_ALIGN = 8        # vertex block alignment (i32 sublane width)
+_E_CHUNK = 128      # edges per streaming chunk (one lane tile)
+_V_ALIGN = 128      # vertex block alignment (one lane tile)
+# Sizes the kernel holds with the label table and the edge list in VMEM;
+# compiled for a v5e at these sizes in tests/test_tpu_compile.py.
+MAX_PALLAS_VERTICES = 1 << 14
+MAX_PALLAS_EDGES = 1 << 18
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _ceil_to(x: int, m: int) -> int:
-    return -(-x // m) * m
+def require_pallas_fits(n_vertices: int, n_edges: int = 0) -> None:
+    """Refuse, at construction, graphs the kernel cannot hold in VMEM."""
+    if n_vertices > MAX_PALLAS_VERTICES or n_edges > MAX_PALLAS_EDGES:
+        raise ValueError(
+            f"use_pallas keeps the labels and edges in VMEM: {n_vertices} "
+            f"vertices / {n_edges} edges exceed the label_prop kernel's "
+            f"limit of {MAX_PALLAS_VERTICES} / {MAX_PALLAS_EDGES}")
 
 
 def label_step_xla(labels: jax.Array, eu: jax.Array,
@@ -63,12 +71,12 @@ def label_step(labels: jax.Array, eu: jax.Array, ev: jax.Array, *,
     padding — the result is shard-count independent.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = default_interpret()
     (n,) = labels.shape
     (e,) = eu.shape
-    block = _ceil_to(-(-n // n_shards), _V_ALIGN)
+    block = ceil_to(-(-n // n_shards), _V_ALIGN)
     n_pad = block * n_shards
-    e_pad = _ceil_to(max(e, 1), _E_CHUNK)
+    e_pad = ceil_to(max(e, 1), _E_CHUNK)
     labels_p = jnp.concatenate(
         [labels.astype(jnp.int32),
          jnp.arange(n, n_pad, dtype=jnp.int32)])
@@ -107,13 +115,12 @@ def _cc_collective(eu: jax.Array, ev: jax.Array, *, n: int, placement):
     stacked trace.  The whole while_loop lives INSIDE one shard_map
     body: D devices, one program, no per-iteration re-dispatch.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     ax = placement.axis
     d = placement.n_devices
     (e,) = eu.shape
-    e_pad = _ceil_to(max(e, 1), d)
+    e_pad = ceil_to(max(e, 1), d)
     # pad with (0, 0) self-loops — the sanitized-edge no-op
     eu_p = jnp.zeros((e_pad,), jnp.int32).at[:e].set(eu.astype(jnp.int32))
     ev_p = jnp.zeros((e_pad,), jnp.int32).at[:e].set(ev.astype(jnp.int32))
@@ -127,9 +134,9 @@ def _cc_collective(eu: jax.Array, ev: jax.Array, *, n: int, placement):
 
         return _fixpoint(step, jnp.arange(n, dtype=jnp.int32))
 
-    fn = shard_map(body, mesh=placement.mesh,
-                   in_specs=(P(ax), P(ax)), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=placement.mesh,
+                       in_specs=(P(ax), P(ax)), out_specs=P(),
+                       check_vma=False)
     return fn(eu_p, ev_p)
 
 

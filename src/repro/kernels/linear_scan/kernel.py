@@ -40,8 +40,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 # ---------------------------------------------------------------------------
 # RWKV-6 chunked kernel
@@ -130,7 +128,7 @@ def rwkv6_scan_bhsd(
             jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u, state0)
@@ -195,7 +193,7 @@ def rglru_scan_bsr(
             jax.ShapeDtypeStruct((B, R), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_r,), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)

@@ -7,11 +7,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
+
 from .kernel import rglru_scan_bsr, rwkv6_scan_bhsd
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -28,7 +26,7 @@ def rwkv6_scan(
 ) -> Tuple[jax.Array, jax.Array]:
     """Chunked RWKV-6 recurrence.  Returns (y (B,S,H,hd) f32, final_state)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = default_interpret()
     B, S, H, hd = r.shape
     chunk = min(chunk, S)
     pad = (-S) % chunk
@@ -65,7 +63,7 @@ def rglru_scan(
 ) -> Tuple[jax.Array, jax.Array]:
     """Diagonal linear recurrence h_t = a_t h_{t-1} + b_t (RG-LRU)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = default_interpret()
     B, S, R = a.shape
     chunk = min(chunk, S)
     pad = (-S) % chunk

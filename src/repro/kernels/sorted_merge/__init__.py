@@ -2,4 +2,5 @@ from .ops import (  # noqa: F401
     merge_compact,
     merge_compact_sharded,
     merge_compact_xla,
+    require_pallas_fits,
 )

@@ -17,8 +17,9 @@ all — only *ranks*:
 are exactly each element's output position, and they are injective (kept-A
 keys are strictly increasing, so are valid-B keys, and cross-run ties are
 impossible).  The kernel computes the ranks with broadcast-compare
-reductions and materializes the output with masked row-minima over an
-output-position tile — the same no-data-dependent-addressing recipe as
+reductions (the exclusive count of kept-A is a rotate-and-add prefix sum)
+and materializes the output with masked minima over an output-position
+tile — the same no-data-dependent-addressing recipe as
 ``kernels/label_prop`` (exactly one candidate matches each output
 position, so the masked min IS the gather; unmatched positions come out
 ``+inf``, which is precisely the padding contract).
@@ -46,44 +47,58 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
+from repro.kernels._rows import col_to_row
 
 INF = jnp.inf
+
+
+def _prefix_sum(x):
+    """Inclusive prefix sum along the lanes of a (1, N) int32 row: log2(N)
+    rotate-and-add steps (the TPU lowering has no cumsum)."""
+    n = x.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    s = 1
+    while s < n:
+        x = x + jnp.where(lane >= s, pltpu.roll(x, s, 1), 0)
+        s *= 2
+    return x
 
 
 def _merge_kernel(bcnt_ref, ak_ref, av_ref, keep_ref, bk_ref, bv_ref,
                   mk_ref, mv_ref, *, n: int, c: int, p_chunk: int):
     shard = pl.program_id(0)
     b_count = bcnt_ref[shard]
-    ak = ak_ref[...]                              # (n,) f32 sorted run A
+    ak = ak_ref[...]                              # (1, n) f32 sorted run A
     av = av_ref[...]
-    keep = keep_ref[...] != 0                     # (n,) survivors of A
-    bk = bk_ref[...]                              # (c,) f32 sorted run B
+    keep = keep_ref[...] != 0                     # (1, n) survivors of A
+    bk = bk_ref[...]                              # (c, 1) f32 sorted run B
     bv = bv_ref[...]
-    lane_b = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)[:, 0]
+    lane_b = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
     b_valid = lane_b < b_count
 
-    # output rank of every kept-A element and every valid-B element
-    # (strictly increasing within each run, no cross-run ties → injective)
-    ex = jnp.cumsum(keep.astype(jnp.int32)) - keep.astype(jnp.int32)
-    ra = ex + jnp.sum((b_valid[None, :] & (bk[None, :] < ak[:, None]))
-                      .astype(jnp.int32), axis=1)             # (n,)
-    rb = lane_b + jnp.sum((keep[None, :] & (ak[None, :] < bk[:, None]))
-                          .astype(jnp.int32), axis=1)         # (c,)
+    # output rank of every kept-A element (a row) and every valid-B
+    # element (a column); strictly increasing within each run, no
+    # cross-run ties → injective
+    keep_i = keep.astype(jnp.int32)
+    ex = _prefix_sum(keep_i) - keep_i
+    ra = ex + jnp.sum((b_valid & (bk < ak)).astype(jnp.int32), axis=0,
+                      keepdims=True)                          # (1, n)
+    rb = lane_b + jnp.sum((keep & (ak < bk)).astype(jnp.int32), axis=1,
+                          keepdims=True)                      # (c, 1)
 
     def chunk(ci, _):
-        base = ci * p_chunk
-        p = base + jax.lax.broadcasted_iota(jnp.int32, (p_chunk, 1),
-                                            0)[:, 0]
-        # masked row-min gather: at most one candidate per output row
-        ma = keep[None, :] & (ra[None, :] == p[:, None])      # (P, n)
-        ka = jnp.min(jnp.where(ma, ak[None, :], INF), axis=1)
-        va = jnp.min(jnp.where(ma, av[None, :], INF), axis=1)
-        mb = b_valid[None, :] & (rb[None, :] == p[:, None])   # (P, c)
-        kb = jnp.min(jnp.where(mb, bk[None, :], INF), axis=1)
-        vb = jnp.min(jnp.where(mb, bv[None, :], INF), axis=1)
-        mk_ref[pl.ds(base, p_chunk)] = jnp.minimum(ka, kb)
-        mv_ref[pl.ds(base, p_chunk)] = jnp.minimum(va, vb)
+        base = pl.multiple_of(ci * p_chunk, p_chunk)
+        # masked min gather: at most one candidate per output position
+        p_col = base + jax.lax.broadcasted_iota(jnp.int32, (p_chunk, 1), 0)
+        ma = keep & (ra == p_col)                             # (P, n)
+        ka = jnp.min(jnp.where(ma, ak, INF), axis=1, keepdims=True)
+        va = jnp.min(jnp.where(ma, av, INF), axis=1, keepdims=True)
+        p_row = base + jax.lax.broadcasted_iota(jnp.int32, (1, p_chunk), 1)
+        mb = b_valid & (rb == p_row)                          # (c, P)
+        kb = jnp.min(jnp.where(mb, bk, INF), axis=0, keepdims=True)
+        vb = jnp.min(jnp.where(mb, bv, INF), axis=0, keepdims=True)
+        mk_ref[:, pl.ds(base, p_chunk)] = jnp.minimum(col_to_row(ka), kb)
+        mv_ref[:, pl.ds(base, p_chunk)] = jnp.minimum(col_to_row(va), vb)
         return 0
 
     jax.lax.fori_loop(0, n // p_chunk, chunk, 0)
@@ -95,43 +110,43 @@ def merge_sharded_vmem(a_keys: jax.Array, a_vals: jax.Array,
                        *, p_chunk: int, interpret: bool = False):
     """Merge-compact all K shards as ONE ``grid=(K,)`` kernel.
 
-    a_keys/a_vals: (K, N) f32 with N divisible by ``p_chunk``; a_keep:
+    a_keys/a_vals: (K, N) f32 with N divisible by ``p_chunk`` (128); a_keep:
     (K, N) int32 0/1; b_keys/b_vals: (K, C) f32 sorted runs; b_count:
     (K,) int32.  Returns ``(m_keys, m_vals)`` each (K, N) f32,
     (+inf, +inf)-padded past the merged length.
+
+    Run A enters as (1, N) rows and run B as (C, 1) columns, so each
+    rank is a reduction along the axis it already lies on.
     """
     K, n = a_keys.shape
     c = b_keys.shape[1]
     assert n % p_chunk == 0
     kernel = functools.partial(_merge_kernel, n=n, c=c, p_chunk=p_chunk)
-    return pl.pallas_call(
+    row = pl.BlockSpec((None, 1, n), lambda k: (k, 0, 0),
+                       memory_space=pltpu.VMEM)
+    col = pl.BlockSpec((None, c, 1), lambda k: (k, 0, 0),
+                       memory_space=pltpu.VMEM)
+    mk, mv = pl.pallas_call(
         kernel,
         grid=(K,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),   # b_count (K,)
-            pl.BlockSpec((None, n), lambda k: (k, 0),
-                         memory_space=pltpu.VMEM),   # a_keys shard
-            pl.BlockSpec((None, n), lambda k: (k, 0),
-                         memory_space=pltpu.VMEM),   # a_vals shard
-            pl.BlockSpec((None, n), lambda k: (k, 0),
-                         memory_space=pltpu.VMEM),   # a_keep shard
-            pl.BlockSpec((None, c), lambda k: (k, 0),
-                         memory_space=pltpu.VMEM),   # b_keys shard
-            pl.BlockSpec((None, c), lambda k: (k, 0),
-                         memory_space=pltpu.VMEM),   # b_vals shard
+            row,                                     # a_keys shard
+            row,                                     # a_vals shard
+            row,                                     # a_keep shard
+            col,                                     # b_keys shard
+            col,                                     # b_vals shard
         ],
-        out_specs=[
-            pl.BlockSpec((None, n), lambda k: (k, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, n), lambda k: (k, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        out_specs=[row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((K, n), jnp.float32),
-            jax.ShapeDtypeStruct((K, n), jnp.float32),
+            jax.ShapeDtypeStruct((K, 1, n), jnp.float32),
+            jax.ShapeDtypeStruct((K, 1, n), jnp.float32),
         ],
-        compiler_params=_compat.CompilerParams(has_side_effects=False),
+        compiler_params=pltpu.CompilerParams(has_side_effects=False),
         interpret=interpret,
-    )(b_count.astype(jnp.int32), a_keys.astype(jnp.float32),
-      a_vals.astype(jnp.float32), a_keep.astype(jnp.int32),
-      b_keys.astype(jnp.float32), b_vals.astype(jnp.float32))
+    )(b_count.astype(jnp.int32), a_keys.astype(jnp.float32)[:, None],
+      a_vals.astype(jnp.float32)[:, None],
+      a_keep.astype(jnp.int32)[:, None],
+      b_keys.astype(jnp.float32)[:, :, None],
+      b_vals.astype(jnp.float32)[:, :, None])
+    return mk[:, 0], mv[:, 0]

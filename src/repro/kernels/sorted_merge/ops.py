@@ -23,18 +23,24 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
+from repro.kernels._rows import ceil_to
+
 from .kernel import merge_sharded_vmem
 
-_P_CHUNK = 256      # output-position tile rows per kernel iteration
+_P_CHUNK = 128      # output positions per kernel iteration (one lane row)
+# Per-shard slots the whole-shard-in-VMEM kernel holds; compiled for a v5e
+# at this size in tests/test_tpu_compile.py (2x more runs out of VMEM).
+MAX_PALLAS_SLOTS = 1 << 14
 INF = jnp.inf
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _ceil_to(x: int, m: int) -> int:
-    return -(-x // m) * m
+def require_pallas_fits(slots: int) -> None:
+    """Refuse, at construction, shards the kernel cannot hold in VMEM."""
+    if slots > MAX_PALLAS_SLOTS:
+        raise ValueError(
+            f"use_pallas keeps each shard in VMEM: {slots} slots per shard "
+            f"exceed the sorted_merge kernel's limit of {MAX_PALLAS_SLOTS}")
 
 
 def merge_compact_xla(a_keys: jax.Array, a_vals: jax.Array,
@@ -85,9 +91,9 @@ def merge_compact_sharded(a_keys: jax.Array, a_vals: jax.Array,
     is shape- and shard-count-independent.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = default_interpret()
     K, n = a_keys.shape
-    n_pad = _ceil_to(max(n, 1), _P_CHUNK)
+    n_pad = ceil_to(max(n, 1), _P_CHUNK)
     if n_pad != n:
         pad = ((0, 0), (0, n_pad - n))
         a_keys = jnp.pad(a_keys, pad, constant_values=jnp.inf)

@@ -10,7 +10,8 @@ finished requests are refilled from the publication list each pass, which
 is exactly the paper's claim — a single combiner with batch-parallel
 execution beats fine-grained per-request dispatch once concurrency is high.
 
-Usage (CPU, reduced config):
+Usage (the published widths by default; ``--reduced`` for the smoke-size
+config the CPU test tier uses):
   python -m repro.launch.serve --arch qwen2_0_5b --sessions 8 --requests 4
 """
 from __future__ import annotations
@@ -27,6 +28,7 @@ import numpy as np
 from repro import configs
 from repro.core import substrate
 from repro.core.faults import FaultPlan
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import lm, transformer
 from repro.serving import PCScheduler, SerialScheduler
 
@@ -176,7 +178,10 @@ def run_serving(arch_id: str = "qwen2_0_5b", *, sessions: int = 8,
                 tier: str = "eliminate",
                 megapass: bool = False,
                 mesh_shards: Optional[int] = None,
-                fault_plan: Optional[FaultPlan] = None) -> Dict[str, Any]:
+                fault_plan: Optional[FaultPlan] = None,
+                reduced: bool = False,
+                structure_kw: Optional[Dict[str, Any]] = None
+                ) -> Dict[str, Any]:
     """Drive ``sessions`` concurrent client sessions through a scheduler.
 
     ``scheduler``: "serial" (one dispatch per request), "pc" (async
@@ -222,6 +227,20 @@ def run_serving(arch_id: str = "qwen2_0_5b", *, sessions: int = 8,
     and the PC scheduler's deadline PQ.  Incompatible with the
     "pc-pallas" scheduler (the kernels assume the stacked layout).
 
+    ``reduced``: decode with the family's smoke-size config
+    (``configs.get_reduced``) instead of the published widths — the CPU
+    test tier's setting.
+
+    ``structure_kw``: constructor arguments merged over the spec's
+    ``serve_kw`` (deployment sizes, preloaded contents such as the PQ's
+    ``values``, the map's ``items`` or the graph's ``edges``).
+
+    Every session's exception is re-raised here after the sessions are
+    joined and the scheduler closed; the returned ``answered`` counts the
+    requests that got an answer, and structure workloads add ``ops``
+    (per method: requests sent, answers that are neither None nor False)
+    and the structure's ``final_size``.
+
     ``fault_plan``: optional deterministic :class:`FaultPlan`
     (DESIGN.md §15) shared between the workload structure (transactional
     guarded dispatch in the graph/map executors) and the PC scheduler
@@ -247,6 +266,7 @@ def run_serving(arch_id: str = "qwen2_0_5b", *, sessions: int = 8,
         if workload == "graph":
             serve_kw["n"] = n_vertices
             serve_kw.setdefault("edge_capacity", 16 * n_vertices)
+        serve_kw.update(structure_kw or {})
         use_pallas = scheduler == "pc-pallas" or (
             workload == "graph" and graph_use_pallas)
         if mesh_pl is not None:
@@ -264,7 +284,7 @@ def run_serving(arch_id: str = "qwen2_0_5b", *, sessions: int = 8,
                                        requests_per_session, read_pct,
                                        serve_kw)
     elif workload == "decode":
-        cfg = configs.get_reduced(arch_id)
+        cfg = (configs.get_reduced if reduced else configs.get)(arch_id)
         ex = DecodeExecutor(cfg, max_batch=max_batch,
                             max_len=prompt_len + n_tokens + 1, seed=seed)
         prompts = rng.integers(2, cfg.vocab,
@@ -294,27 +314,37 @@ def run_serving(arch_id: str = "qwen2_0_5b", *, sessions: int = 8,
         raise ValueError(f"unknown scheduler {scheduler!r}")
 
     results: Dict[int, list] = {}
+    errors: List[BaseException] = []
     t0 = time.time()
 
     def session(sid: int):
         reqs = [(reqs_tab[sid][j],
                  float(sid * requests_per_session + j))
                 for j in range(requests_per_session)]
-        if scheduler == "pc-async":
-            futs = [sch.submit_async(inp, deadline=d) for inp, d in reqs]
-            results[sid] = [f.result() for f in futs]
-        else:
-            results[sid] = [sch.submit(inp, deadline=d) for inp, d in reqs]
+        try:
+            if scheduler == "pc-async":
+                futs = [sch.submit_async(inp, deadline=d)
+                        for inp, d in reqs]
+                results[sid] = [f.result() for f in futs]
+            else:
+                results[sid] = [sch.submit(inp, deadline=d)
+                                for inp, d in reqs]
+        except Exception as e:            # re-raised after the join
+            errors.append(e)
 
     threads = [threading.Thread(target=session, args=(s,))
                for s in range(sessions)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        if isinstance(sch, PCScheduler):
+            sch.close()
     wall = time.time() - t0
-    if isinstance(sch, PCScheduler):
-        sch.close()
+    if errors:
+        raise errors[0]
 
     total_reqs = sessions * requests_per_session
     total_toks = total_reqs * (n_tokens if workload == "decode" else 1)
@@ -329,7 +359,21 @@ def run_serving(arch_id: str = "qwen2_0_5b", *, sessions: int = 8,
         "mean_batch": round(getattr(sch, "mean_batch", 1.0), 2)
         if scheduler != "serial" else 1.0,
         "tier_decisions": dict(getattr(sch, "tier_decisions", {})),
+        "answered": sum(len(r) for r in results.values()),
     }
+    if workload == "decode":
+        stats["answered"] = sum(len(a) == n_tokens for r in results.values()
+                                for a in r)
+    else:
+        ops: Dict[str, List[int]] = {}
+        for sid, answers in results.items():
+            for req, ans in zip(reqs_tab[sid], answers):
+                tally = ops.setdefault(req["method"], [0, 0])
+                tally[0] += 1
+                tally[1] += ans is not None and ans is not False
+        stats["ops"] = ops
+        if hasattr(ex.ds, "__len__"):
+            stats["final_size"] = len(ex.ds)
     if mesh_pl is not None:
         stats["placement"] = mesh_pl.describe()
         stats["mesh_devices"] = mesh_pl.n_devices
@@ -345,7 +389,6 @@ def run_serving(arch_id: str = "qwen2_0_5b", *, sessions: int = 8,
         if isinstance(sch, PCScheduler):
             faults.update(sch.fault_counters())
         stats["faults"] = faults
-    # determinism check: same prompt -> same tokens regardless of batching
     return stats
 
 
@@ -367,8 +410,12 @@ def build_fault_plan(args) -> Optional[FaultPlan]:
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2_0_5b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="decode with the smoke-size config of the family "
+                         "instead of its published widths")
     ap.add_argument("--sessions", type=int, default=8)
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--tokens", type=int, default=8)
@@ -425,7 +472,8 @@ def main():
                         rounds_cap=args.rounds_cap, tier=args.tier,
                         megapass=args.megapass,
                         mesh_shards=args.mesh_shards,
-                        fault_plan=build_fault_plan(args))
+                        fault_plan=build_fault_plan(args),
+                        reduced=args.reduced)
     print("[serve]", stats)
 
 

@@ -607,3 +607,43 @@ def test_merge_compact_sharded_zero_width_batch(K):
         jnp.asarray(bk), jnp.asarray(bk), jnp.zeros((K,), jnp.int32))
     np.testing.assert_array_equal(np.asarray(mk), ak)
     np.testing.assert_array_equal(np.asarray(mv), av)
+
+
+def _pallas_structures():
+    """(name, constructor) just past each kernel's VMEM limit."""
+    from repro.core.batched_map import ShardedMap
+    from repro.core.batched_pq import BatchedPriorityQueue
+    from repro.core.batched_sketch import ShardedSketch
+    from repro.core.batched_union_find import BatchedUnionFind
+    from repro.core.device_graph import DeviceGraph
+    from repro.core.sharded_pq import ShardedBatchedPQ
+    from repro.kernels import _rows
+    from repro.kernels.label_prop import ops as lp
+    from repro.kernels.sorted_merge import ops as sm
+
+    heap = _rows.MAX_HEAP_CAPACITY + 1
+    slots = sm.MAX_PALLAS_SLOTS + 1
+    return {
+        "sharded_pq": lambda: ShardedBatchedPQ(heap, c_max=4, n_shards=2,
+                                               use_pallas=True),
+        "batched_pq": lambda: BatchedPriorityQueue(heap, c_max=4,
+                                                   use_pallas=True),
+        "map": lambda: ShardedMap(slots, c_max=4, use_pallas=True),
+        "sketch": lambda: ShardedSketch(slots, c_max=4, use_pallas=True),
+        "graph_vertices": lambda: DeviceGraph(lp.MAX_PALLAS_VERTICES + 1,
+                                              edge_capacity=64,
+                                              use_pallas=True),
+        "graph_edges": lambda: DeviceGraph(64,
+                                           edge_capacity=lp.MAX_PALLAS_EDGES
+                                           + 1, use_pallas=True),
+        "union_find": lambda: BatchedUnionFind(lp.MAX_PALLAS_VERTICES + 1,
+                                               use_pallas=True),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_pallas_structures()))
+def test_pallas_refused_at_construction_above_vmem_limit(name):
+    """A structure the whole-shard-in-VMEM kernels cannot hold is refused
+    when it is built, naming the limit — never mid-run on the device."""
+    with pytest.raises(ValueError, match="limit"):
+        _pallas_structures()[name]()

@@ -50,9 +50,11 @@ def test_train_other_families(arch):
 
 def test_serving_pc_vs_serial_same_outputs():
     pc = run_serving("qwen2_0_5b", sessions=4, requests_per_session=2,
-                     n_tokens=4, max_batch=4, scheduler="pc", seed=7)
+                     n_tokens=4, max_batch=4, scheduler="pc", seed=7,
+                     reduced=True)
     ser = run_serving("qwen2_0_5b", sessions=4, requests_per_session=2,
-                      n_tokens=4, max_batch=4, scheduler="serial", seed=7)
+                      n_tokens=4, max_batch=4, scheduler="serial", seed=7,
+                      reduced=True)
     assert pc["requests"] == ser["requests"] == 8
     # combining must reduce device dispatches vs serial
     assert pc["device_steps"] <= ser["device_steps"]
