@@ -65,6 +65,7 @@ from repro.kernels.sorted_merge import (merge_compact_sharded,
 from .batched_pq import INF, _flush_subnormals
 from .faults import make_guard
 from .sharded_pq import _flush_host, _route, _route_host, host_key
+from .tracing import TRACER
 
 # All device→host transfers on the map hot path route through this hook
 # so tests can count blocking syncs (same idiom as batched_pq._host_fetch).
@@ -995,26 +996,27 @@ class ShardedMap(substrate.BatchedStructure):
         (DESIGN.md §12).  NO blocking transfer: the per-op result masks
         stay on device and ride the next read's fetch."""
         n_ops = len(methods)
-        opk, opv, code = _encode_update_ops(methods, inputs)
         if n_ops == 0:
             handle = AsyncMapUpdate(self, [], [], self.c_max)
             handle._out = []
             return handle
-        c = self.c_max
-        n_rounds = _pow2(-(-n_ops // c))
-        ks = np.full((n_rounds, c), np.inf, np.float32)
-        vs = np.zeros((n_rounds, c), np.float32)
-        cs = np.zeros((n_rounds, c), np.int32)
-        lane_counts: List[int] = []
-        slices = []
-        for r in range(n_rounds):
-            nc = max(0, min(c, n_ops - r * c))
-            ks[r, :nc] = opk[r * c : r * c + nc]
-            vs[r, :nc] = opv[r * c : r * c + nc]
-            cs[r, :nc] = code[r * c : r * c + nc]
-            lane_counts.append(nc)
-            slices.append((ks[r], vs[r], cs[r], nc))
-        nb = np.asarray(lane_counts, np.int32)
+        with TRACER.span("map.prep"):
+            opk, opv, code = _encode_update_ops(methods, inputs)
+            c = self.c_max
+            n_rounds = _pow2(-(-n_ops // c))
+            ks = np.full((n_rounds, c), np.inf, np.float32)
+            vs = np.zeros((n_rounds, c), np.float32)
+            cs = np.zeros((n_rounds, c), np.int32)
+            lane_counts: List[int] = []
+            slices = []
+            for r in range(n_rounds):
+                nc = max(0, min(c, n_ops - r * c))
+                ks[r, :nc] = opk[r * c : r * c + nc]
+                vs[r, :nc] = opv[r * c : r * c + nc]
+                cs[r, :nc] = code[r * c : r * c + nc]
+                lane_counts.append(nc)
+                slices.append((ks[r], vs[r], cs[r], nc))
+            nb = np.asarray(lane_counts, np.int32)
 
         def commit():
             # guard the WHOLE batch before dispatching anything — atomic:
@@ -1022,24 +1024,27 @@ class ShardedMap(substrate.BatchedStructure):
             # commits the mirror after all of them pass.  It lives inside
             # the dispatch thunk so a transactional restore rewinds the
             # mirror and the device state together (DESIGN.md §15).
-            self._guard_slices(slices)
-            if n_rounds == 1:
-                fn = apply_pass if self.donate else apply_pass_undonated
-                self.state, ok = fn(self.state, jnp.asarray(ks[0]),
-                                    jnp.asarray(vs[0]), jnp.asarray(cs[0]),
-                                    jnp.int32(nb[0]),
-                                    key_range=self.key_range,
-                                    use_pallas=self.use_pallas,
-                                    placement=self._pstatic)
-                return [ok]
-            fn = apply_rounds if self.donate else apply_rounds_undonated
-            self.state, oks = fn(self.state, jnp.asarray(ks),
-                                 jnp.asarray(vs), jnp.asarray(cs),
-                                 jnp.asarray(nb),
-                                 key_range=self.key_range,
-                                 use_pallas=self.use_pallas,
-                                 placement=self._pstatic)
-            return [oks]
+            with TRACER.span("map.prep"):
+                self._guard_slices(slices)
+            with TRACER.span("map.dispatch"):
+                if n_rounds == 1:
+                    fn = apply_pass if self.donate else apply_pass_undonated
+                    self.state, ok = fn(self.state, jnp.asarray(ks[0]),
+                                        jnp.asarray(vs[0]),
+                                        jnp.asarray(cs[0]),
+                                        jnp.int32(nb[0]),
+                                        key_range=self.key_range,
+                                        use_pallas=self.use_pallas,
+                                        placement=self._pstatic)
+                    return [ok]
+                fn = apply_rounds if self.donate else apply_rounds_undonated
+                self.state, oks = fn(self.state, jnp.asarray(ks),
+                                     jnp.asarray(vs), jnp.asarray(cs),
+                                     jnp.asarray(nb),
+                                     key_range=self.key_range,
+                                     use_pallas=self.use_pallas,
+                                     placement=self._pstatic)
+                return [oks]
 
         if self._guard is None:
             masks = commit()
@@ -1062,12 +1067,14 @@ class ShardedMap(substrate.BatchedStructure):
             return None
         # `+ 0` detaches the sizes from state.size, which a later donated
         # apply would invalidate (fetching a donated buffer throws)
-        fetched = _host_fetch(([h.masks for h in todo],
-                               self.state.size + 0, extra))
-        for h, masks_h in zip(todo, fetched[0]):
-            h._resolve(masks_h)
-            self._unresolved.remove(h)
-        self._refresh_sizes(fetched[1])
+        with TRACER.span("map.fetch"):
+            fetched = _host_fetch(([h.masks for h in todo],
+                                   self.state.size + 0, extra))
+        with TRACER.span("map.convert"):
+            for h, masks_h in zip(todo, fetched[0]):
+                h._resolve(masks_h)
+                self._unresolved.remove(h)
+            self._refresh_sizes(fetched[1])
         return fetched[2]
 
     # ``update_batch`` / generic ``apply`` inherit from BatchedStructure
@@ -1091,16 +1098,20 @@ class ShardedMap(substrate.BatchedStructure):
         nq = len(methods)
         if nq == 0:
             return []
-        qa0, qb0, kind0 = _encode_read_ops(methods, inputs)
-        qa = np.zeros((_pow2(nq),), np.float32)
-        qb = np.full((_pow2(nq),), -1.0, np.float32)
-        kind = np.full((_pow2(nq),), RD_COUNT, np.int32)  # pad: count 0
-        qa[:nq], qb[:nq], kind[:nq] = qa0, qb0, kind0
-        res, ok = read_pass(self.state, jnp.asarray(qa), jnp.asarray(qb),
-                            jnp.asarray(kind), placement=self._pstatic)
+        with TRACER.span("map.prep"):
+            qa0, qb0, kind0 = _encode_read_ops(methods, inputs)
+            qa = np.zeros((_pow2(nq),), np.float32)
+            qb = np.full((_pow2(nq),), -1.0, np.float32)
+            kind = np.full((_pow2(nq),), RD_COUNT, np.int32)  # pad: count 0
+            qa[:nq], qb[:nq], kind[:nq] = qa0, qb0, kind0
+        with TRACER.span("map.dispatch"):
+            res, ok = read_pass(self.state, jnp.asarray(qa),
+                                jnp.asarray(qb), jnp.asarray(kind),
+                                placement=self._pstatic)
         got = self._resolve_through(None, extra=(res, ok))
-        res_h, ok_h = np.asarray(got[0]), np.asarray(got[1])
-        return _convert_read_results(methods, res_h, ok_h)
+        with TRACER.span("map.convert"):
+            res_h, ok_h = np.asarray(got[0]), np.asarray(got[1])
+            return _convert_read_results(methods, res_h, ok_h)
 
     def lookup(self, key: float) -> Optional[float]:
         return self.read_batch(["lookup"], [key])[0]

@@ -53,10 +53,9 @@ from __future__ import annotations
 import heapq
 import queue
 import threading
-import time
 from collections import deque
 from concurrent.futures import Future
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Deque, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -66,6 +65,7 @@ from repro.core.combining import (ALL_TIERS, TIER_DEVICE, TIER_ELIMINATE,
 from repro.core.faults import (CircuitBreaker, DispatchGuard, FaultPlan,
                                InjectedCombinerKill)
 from repro.core.sharded_pq import ShardedBatchedPQ, host_key
+from repro.core.tracing import TRACER
 
 _SENTINEL = object()
 
@@ -96,7 +96,6 @@ class BatchRequest:
 
     inputs: Any                       # per-request input (np array row / dict)
     deadline: float = 0.0             # smaller = more urgent
-    submitted_at: float = field(default_factory=time.monotonic)
 
 
 @dataclass
@@ -107,6 +106,8 @@ class _Entry:
     future: Future
     key: float = 0.0                  # f32-quantized deadline (PQ dtype)
     epoch: int = 0                    # per-entry id (exactly-once recovery)
+    t_pub: int = 0                    # tracer stamps (0: tracer off)
+    t_chosen: int = 0
 
 
 class PCScheduler:
@@ -261,7 +262,8 @@ class PCScheduler:
         if deadline != deadline:        # reject NaN at the client boundary
             raise ValueError("deadline must not be NaN")
         f: Future = Future()
-        ent = _Entry(BatchRequest(inputs=inputs, deadline=deadline), f)
+        ent = _Entry(BatchRequest(inputs=inputs, deadline=deadline), f,
+                     t_pub=TRACER.stamp())
         with self._cond:
             alive = self._combiner.is_alive() or (
                 self._supervisor is not None and self._supervisor.is_alive())
@@ -376,6 +378,11 @@ class PCScheduler:
                 # inconsistent, so flush leftovers too) and keep serving
                 self._abort_pending(new, exc)
                 continue
+            t_chosen = TRACER.stamp()
+            if t_chosen:
+                for chosen in chosen_rounds:
+                    for ent in chosen:
+                        ent.t_chosen = t_chosen
             for chosen in chosen_rounds:
                 self.passes += 1
                 self.batches.append(len(chosen))
@@ -607,7 +614,8 @@ class PCScheduler:
     # -- device side ---------------------------------------------------------
     def _device_loop(self) -> None:
         while True:
-            batch = self._handoff.get()
+            with TRACER.span("scheduler.wait_batch"):
+                batch = self._handoff.get()
             if batch is _SENTINEL:
                 return
             self._run_batch(batch)
@@ -616,9 +624,19 @@ class PCScheduler:
         with self._cond:
             self._inflight += 1
         try:
+            t_start = TRACER.stamp()
             outs = list(self.step_fn([e.req.inputs for e in batch]))
-            for ent, out in zip(batch, outs):
-                _resolve_future(ent.future, out)   # client may have cancelled
+            t_end = TRACER.stamp()
+            with TRACER.span("scheduler.resolve"):
+                # _resolve_future: the client may have cancelled
+                for ent, out in zip(batch, outs):
+                    _resolve_future(ent.future, out)
+            if t_start and all(e.t_pub and e.t_chosen for e in batch):
+                # stage stamps (DESIGN.md §19); a batch published before
+                # the tracer started has no stamps to sum
+                TRACER.batch(t_start, t_end, len(batch),
+                             sum(e.t_chosen - e.t_pub for e in batch),
+                             sum(t_start - e.t_chosen for e in batch))
             if len(outs) < len(batch):
                 # a short return must not strand the tail forever
                 raise RuntimeError(
