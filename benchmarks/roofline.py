@@ -122,7 +122,7 @@ def kernel_cases() -> List[Tuple[str, str, int, Callable[[], object]]]:
 
     from repro.core.batched_pq import _k_smallest
     from repro.kernels.label_prop.ops import label_step_xla
-    from repro.kernels.sorted_merge.ops import merge_compact_xla
+    from repro.kernels.sorted_merge.ops import merge_edits_xla
 
     rng = np.random.default_rng(0)
     cases: List[Tuple[str, str, int, Callable[[], object]]] = []
@@ -142,24 +142,27 @@ def kernel_cases() -> List[Tuple[str, str, int, Callable[[], object]]]:
         K * cap * 4 + K * c_max * 8,
         lambda: jax.block_until_ready(kmin(heaps, sizes))))
 
-    # sorted_merge: one merge-compact (PQ combining phase 4).  Evens in
-    # the sorted run, odds in the insert run — disjoint, both strictly
-    # increasing; C lanes dropped from A so the merge fits N.
+    # sorted_merge: one bounded-edit merge (the map's update pass).
+    # Evens in the sorted run, odds in the insert run — disjoint, both
+    # strictly increasing; the last C slots of A deleted so the merge
+    # fits N.
     N, C = 1 << 15, 64
     a_keys = jnp.asarray((np.arange(N) * 2.0).astype(np.float32))
     a_vals = a_keys + 0.5
-    a_keep = jnp.asarray(np.arange(N) < N - C)
+    a_size = jnp.int32(N)
+    d_slots = jnp.arange(N - C, N, dtype=jnp.int32)
     b_keys = jnp.asarray((np.arange(C) * 2.0 + 1.0).astype(np.float32))
     b_vals = b_keys + 0.5
     b_count = jnp.int32(C)
-    merge = jax.jit(merge_compact_xla)
-    jax.block_until_ready(merge(a_keys, a_vals, a_keep, b_keys, b_vals,
-                                b_count))
+    merge = jax.jit(merge_edits_xla)
+    jax.block_until_ready(merge(a_keys, a_vals, a_size, d_slots, b_keys,
+                                b_vals, b_count))
     cases.append((
         "sorted_merge", f"N={N} C={C}",
-        2 * N * 4 + N * 1 + 2 * C * 4 + 2 * N * 4,
+        2 * N * 4 + C * 4 + 2 * C * 4 + 2 * N * 4,
         lambda: jax.block_until_ready(
-            merge(a_keys, a_vals, a_keep, b_keys, b_vals, b_count))))
+            merge(a_keys, a_vals, a_size, d_slots, b_keys, b_vals,
+                  b_count))))
 
     # label_prop: one scatter-min + pointer-jump iteration (graph full
     # rebuild inner step) over a random edge multiset.

@@ -13,10 +13,11 @@ combining pass of mixed updates a **sort-merge**:
   Per-lane results follow the last-earlier-same-key chain rule (an op's
   outcome fully determines presence for the next op on that key, exactly
   the device graph's rule); the array takes only the NET effect per key
-  class: deletions become a ``keep`` mask, insertions become a short
+  class: deletions become ≤ c_max slots, insertions become a short
   sorted run, in-place value writes scatter at their slot, and one
-  **merge-compact** (``kernels/sorted_merge``) rebuilds the sorted array
-  — rank arithmetic + scatter in the XLA twin, a ``grid=(K,)``
+  **merge** (``kernels/sorted_merge``) rebuilds the sorted array — the
+  bounded-edit merge (``merge_edits_xla``: prefix sums and static shift
+  stages, nothing addressed N-wide) on the XLA path, a ``grid=(K,)``
   broadcast-compare kernel under ``use_pallas=True``.
 * **vectorized batched reads** — ``lookup``, ``range_count``,
   ``range_sum`` (closed interval [lo, hi]) and ``kth_smallest`` are ONE
@@ -59,7 +60,7 @@ import numpy as np
 from . import placement as _placement
 from . import substrate
 from repro.kernels.sorted_merge import (merge_compact_sharded,
-                                        merge_compact_xla,
+                                        merge_edits_xla,
                                         require_pallas_fits)
 
 from .batched_pq import INF, _flush_subnormals
@@ -120,13 +121,14 @@ def _pow2(m: int) -> int:
 # Fused mixed-op apply pass (donated) — net-effect sort-merge
 # ---------------------------------------------------------------------------
 def _prep_one(keys1, vals1, size1, k1, v1, code1, nb1, *, c_max: int):
-    """Net a shard's ≤ c_max op row down to merge-compact inputs.
+    """Net a shard's ≤ c_max op row down to a bounded edit.
 
-    Returns ``(keys1, vals1, keep, b_keys, b_vals, b_count, new_size,
-    ok)``: the value-updated arrays, the survivor mask over the body, the
-    sorted run of netted-in pairs, and the per-lane arrival-order results
-    (the chain rule, see module docstring).  Pure XLA, vmapped over the
-    shard axis by :func:`_apply_impl`.
+    Returns ``(keys1, vals1, d_slots, b_keys, b_vals, b_count, new_size,
+    ok)``: the value-updated arrays, the (c,) slots netted out (``cap``
+    on lanes that delete nothing), the sorted run of netted-in pairs, and
+    the per-lane arrival-order results (the chain rule, see module
+    docstring).  Pure XLA, vmapped over the shard axis by
+    :func:`_apply_impl`.
     """
     cap = keys1.shape[0] - 1
     lane = jnp.arange(c_max, dtype=jnp.int32)
@@ -176,10 +178,8 @@ def _prep_one(keys1, vals1, size1, k1, v1, code1, nb1, *, c_max: int):
     vals1 = vals1.at[tgt].set(jnp.where(upd, final_val, vals1[tgt]))
     vals1 = vals1.at[cap].set(INF)                        # scratch stays pad
 
-    # deletions become the merge's keep mask
-    rflag = jnp.zeros((cap + 1,), jnp.bool_)
-    rflag = rflag.at[jnp.where(rem, pos, cap)].set(rem)
-    keep = (jnp.arange(cap) < size1) & ~rflag[:cap]
+    # deletions become ≤ c slots of the body
+    d_slots = jnp.where(rem, pos, cap)
 
     # insertions become the sorted b-run (stable argsort; distinct keys)
     bkey_raw = jnp.where(add, k1, INF)
@@ -188,7 +188,7 @@ def _prep_one(keys1, vals1, size1, k1, v1, code1, nb1, *, c_max: int):
     b_vals = jnp.where(add, final_val, INF)[order]
     b_count = jnp.sum(add.astype(jnp.int32))
     new_size = size1 - jnp.sum(rem.astype(jnp.int32)) + b_count
-    return keys1, vals1, keep, b_keys, b_vals, b_count, new_size, ok
+    return keys1, vals1, d_slots, b_keys, b_vals, b_count, new_size, ok
 
 
 def _apply_impl(state: MapState, op_keys: jax.Array, op_vals: jax.Array,
@@ -239,18 +239,21 @@ def _apply_impl(state: MapState, op_keys: jax.Array, op_vals: jax.Array,
     rows_c = jax.vmap(scatter_row, in_axes=(0, 0, None))(
         dest, jnp.where(one_hot, op_code[None, :], 0), 0)
 
-    keys2, vals2, keep, b_keys, b_vals, b_count, new_size, ok_rows = \
+    keys2, vals2, d_slots, b_keys, b_vals, b_count, new_size, ok_rows = \
         jax.vmap(lambda a, b, s, rk, rv, rc, n: _prep_one(
             a, b, s, rk, rv, rc, n, c_max=c))(
             keys, vals, size, rows_k, rows_v, rows_c, counts)
 
-    # merge-compact every shard: ONE grid=(K,) kernel or the vmapped twin
+    # merge every shard: ONE grid=(K,) kernel, or the vmapped bounded-edit
+    # merge (≤ c deletions and ≤ c inserts a shard)
     if use_pallas:
+        keep = jax.vmap(lambda s, d: (jnp.arange(cap) < s) & ~jnp.zeros(
+            (cap,), jnp.bool_).at[d].set(True, mode="drop"))(size, d_slots)
         mk, mv = merge_compact_sharded(keys2[:, :cap], vals2[:, :cap],
                                        keep, b_keys, b_vals, b_count)
     else:
-        mk, mv = jax.vmap(merge_compact_xla)(
-            keys2[:, :cap], vals2[:, :cap], keep, b_keys, b_vals,
+        mk, mv = jax.vmap(merge_edits_xla)(
+            keys2[:, :cap], vals2[:, :cap], size, d_slots, b_keys, b_vals,
             b_count)
     pad = jnp.full((K, 1), INF, jnp.float32)
     state = MapState(jnp.concatenate([mk, pad], axis=1),
@@ -465,7 +468,7 @@ mixed_pass_undonated = jax.jit(_mixed_impl, static_argnames=_STATIC)
 # ---------------------------------------------------------------------------
 # Same shape as the PQ's mesh twin: routing (O(K·c), tiny) is computed
 # replicated on every device against GLOBAL shard ids, each device runs
-# the net-effect prep + merge-compact on its K/D local shard rows only
+# the net-effect prep + bounded-edit merge on its K/D local shard rows only
 # (the O(c² + capacity) work scale-out parallelizes), and the arrival-
 # order result gather / global read reductions become collectives.
 # all_gather's device-major stacking makes global shard k = d·K/D + j —
@@ -513,12 +516,13 @@ def _mesh_apply_body(keys, vals, size, op_keys, op_vals, op_code, nb,
     rows_c = jax.vmap(scatter_row, in_axes=(0, 0, None))(
         dest, jnp.where(one_hot, op_code[None, :], 0), 0)
 
-    keys2, vals2, keep, b_keys, b_vals, b_count, new_size, ok_rows = \
+    keys2, vals2, d_slots, b_keys, b_vals, b_count, new_size, ok_rows = \
         jax.vmap(lambda a, b, s, rk, rv, rc, n: _prep_one(
             a, b, s, rk, rv, rc, n, c_max=c))(
             keys, vals, size, rows_k, rows_v, rows_c, counts)
-    mk, mv = jax.vmap(merge_compact_xla)(
-        keys2[:, :cap], vals2[:, :cap], keep, b_keys, b_vals, b_count)
+    mk, mv = jax.vmap(merge_edits_xla)(
+        keys2[:, :cap], vals2[:, :cap], size, d_slots, b_keys, b_vals,
+        b_count)
     pad = jnp.full((K_local, 1), INF, jnp.float32)
     new_keys = jnp.concatenate([mk, pad], axis=1)
     new_vals = jnp.concatenate([mv, pad], axis=1)

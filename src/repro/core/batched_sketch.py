@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.sorted_merge import (merge_compact_sharded,
-                                        merge_compact_xla,
+                                        merge_edits_xla,
                                         require_pallas_fits)
 
 from . import substrate
@@ -70,9 +70,9 @@ class SketchState(NamedTuple):
 def _prep_one(keys1, counts1, size1, k1, w1, nb1, *, c_max: int):
     """Net a shard's ≤ c_max add row down to merge-compact inputs.
 
-    Returns ``(counts1, keep, b_keys, b_counts, b_count, new_size, ok)``:
-    the bumped counts, the (all-live) keep mask, the sorted run of new
-    counters, and per-lane created flags.  Increments commute, so the
+    Returns ``(counts1, b_keys, b_counts, b_count, new_size, ok)``: the
+    bumped counts, the sorted run of new counters, and per-lane created
+    flags.  Nothing is deleted: every live slot survives the merge.  Increments commute, so the
     chain rule collapses: one representative lane per key class carries
     the class's WEIGHT TOTAL, and only the first lane of an absent key
     reports created=True.
@@ -99,9 +99,6 @@ def _prep_one(keys1, counts1, size1, k1, w1, nb1, *, c_max: int):
     counts1 = counts1.at[tgt].add(jnp.where(upd, wsum, 0.0))
     counts1 = counts1.at[cap].set(INF)                    # scratch stays pad
 
-    # no deletions: every live slot survives the merge
-    keep = jnp.arange(cap) < size1
-
     # new counters become the sorted b-run (distinct keys by rep-ness)
     add = is_rep & ~in_tab
     bkey_raw = jnp.where(add, k1, INF)
@@ -110,7 +107,7 @@ def _prep_one(keys1, counts1, size1, k1, w1, nb1, *, c_max: int):
     b_counts = jnp.where(add, wsum, INF)[order]
     b_count = jnp.sum(add.astype(jnp.int32))
     new_size = size1 + b_count
-    return counts1, keep, b_keys, b_counts, b_count, new_size, ok
+    return counts1, b_keys, b_counts, b_count, new_size, ok
 
 
 def _apply_impl(state: SketchState, op_keys: jax.Array, op_w: jax.Array,
@@ -147,18 +144,22 @@ def _apply_impl(state: SketchState, op_keys: jax.Array, op_w: jax.Array,
         dest, jnp.where(one_hot, w[None, :], jnp.float32(0)),
         jnp.float32(0))
 
-    counts2, keep, b_keys, b_counts, b_count, new_size, ok_rows = \
+    counts2, b_keys, b_counts, b_count, new_size, ok_rows = \
         jax.vmap(lambda a, b, s, rk, rw, n: _prep_one(
             a, b, s, rk, rw, n, c_max=c))(
             keys, counts, size, rows_k, rows_w, cnts)
 
+    # insert-only merge of every shard: ONE grid=(K,) kernel, or the
+    # vmapped bounded-edit merge with no deletion slots
     if use_pallas:
+        keep = jnp.arange(cap)[None, :] < size[:, None]
         mk, mc = merge_compact_sharded(keys[:, :cap], counts2[:, :cap],
                                        keep, b_keys, b_counts, b_count)
     else:
-        mk, mc = jax.vmap(merge_compact_xla)(
-            keys[:, :cap], counts2[:, :cap], keep, b_keys, b_counts,
-            b_count)
+        no_dels = jnp.zeros((K, 0), jnp.int32)
+        mk, mc = jax.vmap(merge_edits_xla)(
+            keys[:, :cap], counts2[:, :cap], size, no_dels, b_keys,
+            b_counts, b_count)
     pad = jnp.full((K, 1), INF, jnp.float32)
     state = SketchState(jnp.concatenate([mk, pad], axis=1),
                         jnp.concatenate([mc, pad], axis=1), new_size)

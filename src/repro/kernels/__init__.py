@@ -19,7 +19,8 @@ Kernels:
                     wavefront over a VMEM-resident array heap.
   heap_insert     — paper §4 Insert phase: level-synchronous collective
                     insert with InsertSet split rows.
-  sorted_merge    — the ordered map's merge-compact rebuild.
+  sorted_merge    — the ordered map's and the sketch's merge-compact
+                    rebuild (and its bounded-edit XLA merge).
   label_prop      — one scatter-min + pointer-jump connectivity step.
 """
 from __future__ import annotations

@@ -1,6 +1,6 @@
 from .ops import (  # noqa: F401
     merge_compact,
     merge_compact_sharded,
-    merge_compact_xla,
+    merge_edits_xla,
     require_pallas_fits,
 )

@@ -31,12 +31,12 @@ cross-program communication.  Output positions stream through a
 ``fori_loop`` in chunks of ``p_chunk`` rows, so the live mask working set
 is O(p_chunk · N) — with p_chunk=256 that prices the compiled kernel at
 roughly N ≲ 8K slots per shard under the ~16 MiB VMEM budget (the map's
-benchmark scale); the XLA twin has no such bound.
+benchmark scale); the XLA path has no such bound.
 
 Determinism: the merge moves f32 bits without arithmetic and min-
 reductions over a single live candidate are exact, so the kernel, the XLA
-twin (``ops.merge_compact_xla``) and the numpy oracle (``ref.py``) agree
-element-wise for every shard count.
+path (``ops.merge_edits_xla``, for bounded edits) and the numpy oracle
+(``ref.py``) agree element-wise for every shard count.
 """
 from __future__ import annotations
 

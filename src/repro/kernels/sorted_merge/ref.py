@@ -3,7 +3,7 @@
 ``merge_compact_reference`` is the element-wise twin of one merge pass in
 plain numpy: drop the entries of sorted run A whose ``keep`` flag is off,
 merge the survivors with the valid prefix of sorted run B, and pad the
-tail with ``(+inf, +inf)``.  The Pallas kernel, the XLA twin and this
+tail with ``(+inf, +inf)``.  The Pallas kernel, the XLA merge and this
 oracle must agree bit-exactly (the merge moves f32 values without any
 arithmetic) for every shard count — the same contract as
 ``kernels/label_prop``.

@@ -1,6 +1,8 @@
 """Batched ordered map (DESIGN.md §13): semantics, reads, rounds,
 occupancy guard, one-sync contract — deterministic tier-1 suite plus
 seeded differential fuzz at K ∈ {1, 4, 8}."""
+import math
+
 import numpy as np
 import pytest
 
@@ -227,6 +229,77 @@ def test_donated_and_undonated_agree():
             (60.0, 9.0)])
     assert a.update_batch(*ops) == b.update_batch(*ops)
     assert a.items() == b.items()
+
+
+# ---------------------------------------------------------------------------
+# mechanism guard: the update programs address ≤ K·c rows by index
+# ---------------------------------------------------------------------------
+def _lowered_update_program(name, K, cap, c, R=4):
+    import jax
+    import jax.numpy as jnp
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    state = bm.MapState(spec((K, cap + 1)), spec((K, cap + 1)),
+                        spec((K,), jnp.int32))
+    static = dict(key_range=(0.0, 1.0), use_pallas=False, placement=None)
+    if name == "apply_pass":
+        args = (spec((c,)), spec((c,)), spec((c,), jnp.int32),
+                spec((), jnp.int32))
+    elif name == "apply_rounds":
+        args = (spec((R, c)), spec((R, c)), spec((R, c), jnp.int32),
+                spec((R,), jnp.int32))
+    else:
+        args = (spec((R,), jnp.int32), spec((R, c)), spec((R, c)),
+                spec((R, c), jnp.int32), spec((R,), jnp.int32))
+    return getattr(bm, name).lower(state, *args, **static)
+
+
+def _walk(op):
+    for region in op.regions:
+        for block in region.blocks:
+            for inner in block.operations:
+                yield inner
+                yield from _walk(inner)
+
+
+@pytest.mark.parametrize("program", ["apply_pass", "apply_rounds",
+                                     "mixed_pass"])
+def test_update_programs_address_at_most_k_c_rows(program):
+    """At the benchmark's shard size no scatter or gather in an update
+    program takes more than K·c index rows, and no value is a (K, cap, c)
+    broadcast: the merge moves the shard by static shifts, not by an
+    N-wide scatter or an N×c rank compare.  Lowering only, nothing runs."""
+    import re
+
+    from jax._src.interpreters import mlir
+
+    K, cap, c = 4, 1 << 21, 64
+    module = _lowered_update_program(program, K, cap, c).compiler_ir(
+        "stablehlo")
+    n_addressed = 0
+    for op in _walk(module.operation):
+        name = op.operation.name
+        values = list(op.operands) + list(op.results)
+        for v in values:
+            if isinstance(v.type, mlir.ir.RankedTensorType):
+                shape = tuple(v.type.shape)
+                assert shape != (K, cap, c), (program, name, shape)
+                assert math.prod(shape) <= 2 * K * (cap + 1), \
+                    (program, name, shape)
+        if name not in ("stablehlo.scatter", "stablehlo.gather"):
+            continue
+        dn = op.attributes["scatter_dimension_numbers"
+                           if name.endswith("scatter")
+                           else "dimension_numbers"]
+        m = re.search(r"index_vector_dim = (\d+)", str(dn))
+        ivd = int(m.group(1)) if m else 0
+        idx = list(mlir.ir.RankedTensorType(op.operands[1].type).shape)
+        rows = math.prod(d for i, d in enumerate(idx) if i != ivd)
+        assert rows <= K * c, (program, name, idx)
+        n_addressed += 1
+    assert n_addressed > 0
 
 
 # ---------------------------------------------------------------------------

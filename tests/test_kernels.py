@@ -24,7 +24,7 @@ from repro.kernels.linear_scan import rglru_scan, rwkv6_scan
 from repro.kernels.linear_scan.ref import rglru_reference, rwkv6_reference
 from repro.kernels.sorted_merge import (merge_compact,
                                         merge_compact_sharded,
-                                        merge_compact_xla)
+                                        merge_edits_xla)
 from repro.kernels.sorted_merge.ref import merge_compact_reference
 
 
@@ -389,23 +389,20 @@ def _merge_case(rng, n, c):
 
 @pytest.mark.parametrize("trial", range(4))
 def test_merge_compact_kernel_bit_exact(trial):
-    """Kernel ≡ XLA twin ≡ numpy ref ELEMENT-WISE (keys AND values),
-    ragged sizes included — the merge moves f32 bits, no arithmetic."""
+    """Kernel ≡ numpy ref ELEMENT-WISE (keys AND values) for an
+    arbitrary keep mask, ragged sizes included — the merge moves f32
+    bits, no arithmetic."""
     rng = np.random.default_rng(900 + trial)
     n = int(rng.integers(8, 70))                       # not tile-aligned
     c = int(rng.integers(1, 8))
     a_keys, a_vals, keep, b_keys, b_vals, bc = _merge_case(rng, n, c)
     want = merge_compact_reference(a_keys, a_vals, keep, b_keys, b_vals,
                                    bc)
-    got_x = merge_compact_xla(jnp.asarray(a_keys), jnp.asarray(a_vals),
-                              jnp.asarray(keep), jnp.asarray(b_keys),
-                              jnp.asarray(b_vals), jnp.int32(bc))
-    got_k = merge_compact(jnp.asarray(a_keys), jnp.asarray(a_vals),
-                          jnp.asarray(keep), jnp.asarray(b_keys),
-                          jnp.asarray(b_vals), jnp.int32(bc))
-    for got in (got_x, got_k):
-        np.testing.assert_array_equal(np.asarray(got[0]), want[0])
-        np.testing.assert_array_equal(np.asarray(got[1]), want[1])
+    got = merge_compact(jnp.asarray(a_keys), jnp.asarray(a_vals),
+                        jnp.asarray(keep), jnp.asarray(b_keys),
+                        jnp.asarray(b_vals), jnp.int32(bc))
+    np.testing.assert_array_equal(np.asarray(got[0]), want[0])
+    np.testing.assert_array_equal(np.asarray(got[1]), want[1])
 
 
 @pytest.mark.parametrize("n_shards", [1, 3, 4, 5, 8])
@@ -467,6 +464,95 @@ def test_merge_compact_empty_and_full_cases():
                         jnp.int32(0))
     np.testing.assert_array_equal(np.asarray(got[0]), full_k)
     np.testing.assert_array_equal(np.asarray(got[1]), full_v)
+
+
+def _edit_case(rng, n, c, kind):
+    """One shard's bounded edit for :func:`merge_edits_xla`: a live run of
+    ``size`` keys, ≤ c deletion slots (lane order shuffled, unused lanes
+    at n; no slots at all for ``ins_only``, the sketch's insert-only
+    merge) and ≤ c sorted inserts absent from the kept run."""
+    pool = rng.permutation(np.arange(1, 8 * (n + c) + 8)).astype(np.float32)
+    if kind == "none":
+        size, dels, bc = int(rng.integers(0, n + 1)), [], 0
+    elif kind == "ins_only":                  # no deletion slots at all
+        size, dels = int(rng.integers(0, n + 1)), []
+        bc = int(rng.integers(0, min(c, n - size) + 1))
+    elif kind in ("del_front", "del_back"):
+        size = n
+        nd = min(c, size)
+        dels = list(range(nd)) if kind == "del_front" \
+            else list(range(size - nd, size))
+        bc = 0
+    elif kind == "del_at_size":
+        size = int(rng.integers(min(c, n), n + 1))
+        dels, bc = list(range(size - min(c, size), size)), 0
+    elif kind in ("ins_below", "ins_above"):
+        size, dels, bc = n - min(c, n), [], min(c, n)
+    elif kind == "full":
+        size = int(rng.integers(max(0, n - c), n + 1))
+        nd = int(rng.integers(0, min(size, c - n + size) + 1))
+        dels = sorted(rng.choice(size, nd, replace=False).tolist())
+        bc = n - size + nd
+    else:                                              # "mixed"
+        size = int(rng.integers(0, n + 1))
+        nd = int(rng.integers(0, min(c, size) + 1))
+        dels = sorted(rng.choice(size, nd, replace=False).tolist())
+        bc = int(rng.integers(0, min(c, n - size + nd) + 1))
+    a = np.sort(pool[:size])
+    b = np.sort(pool[size : size + bc])
+    if kind == "ins_below":
+        a = np.sort(pool[:size] + 8 * (n + c) + 8)
+    elif kind == "ins_above":
+        b = np.sort(pool[size : size + bc] + 8 * (n + c) + 8)
+    a_keys = np.full((n,), np.inf, np.float32)
+    a_vals = np.full((n,), np.inf, np.float32)
+    a_keys[:size] = a
+    a_vals[:size] = rng.uniform(-9, 9, size).astype(np.float32)
+    d_slots = np.full((0 if kind == "ins_only" else c,), n, np.int32)
+    d_slots[:len(dels)] = dels
+    d_slots = rng.permutation(d_slots)
+    b_keys = np.full((c,), np.inf, np.float32)
+    b_vals = np.full((c,), np.inf, np.float32)
+    b_keys[:bc] = b
+    b_vals[:bc] = rng.uniform(-9, 9, bc).astype(np.float32)
+    keep = np.arange(n) < size
+    keep[dels] = False
+    return (a_keys, a_vals, size, d_slots, b_keys, b_vals, bc), keep
+
+
+EDIT_CASES = [
+    ("none", 37, 5), ("none", 1, 1), ("none", 200, 64),
+    ("del_front", 200, 64), ("del_back", 200, 64), ("del_back", 1, 1),
+    ("del_at_size", 513, 64), ("del_at_size", 37, 5),
+    ("ins_below", 200, 64), ("ins_above", 200, 64), ("ins_below", 1, 1),
+    ("ins_above", 37, 5),
+    ("full", 64, 64), ("full", 37, 5), ("full", 1, 1),
+    ("mixed", 200, 64), ("mixed", 513, 5), ("mixed", 37, 1),
+    ("mixed", 64, 64), ("mixed", 300, 130), ("mixed", 16640, 64),
+    ("ins_only", 37, 5), ("ins_only", 200, 64), ("ins_only", 1, 1),
+]
+
+
+@pytest.mark.parametrize("K", [1, 4])
+@pytest.mark.parametrize("kind,n,c", EDIT_CASES)
+def test_merge_edits_xla_matches_reference(kind, n, c, K):
+    """The bounded-edit merge, vmapped over K shards, gives every shard
+    the numpy oracle's BITS (keys and values), the keep mask being the
+    live run less the deletion slots."""
+    rng = np.random.default_rng(1400 + 2 * EDIT_CASES.index((kind, n, c))
+                                + K)
+    cases = [_edit_case(rng, n, c, kind) for _ in range(K)]
+    stk = [jnp.asarray(np.stack([np.asarray(cs[0][i]) for cs in cases]))
+           for i in range(7)]
+    mk, mv = jax.jit(jax.vmap(merge_edits_xla))(*stk)
+    for (args, keep), gk, gv in zip(cases, np.asarray(mk), np.asarray(mv)):
+        a_keys, a_vals, _, _, b_keys, b_vals, bc = args
+        wk, wv = merge_compact_reference(a_keys, a_vals, keep, b_keys,
+                                         b_vals, bc)
+        np.testing.assert_array_equal(gk.view(np.uint32),
+                                      wk.view(np.uint32))
+        np.testing.assert_array_equal(gv.view(np.uint32),
+                                      wv.view(np.uint32))
 
 
 # ---------------------------------------------------------------------------

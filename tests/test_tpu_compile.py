@@ -115,11 +115,12 @@ def test_pq_rounds_program_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().argument_size_in_bytes >= K * cap * 4
 
 
-def test_map_apply_pass_compiles_for_v5e(one_chip):
-    """The served map's fused mixed-op update pass (XLA merge-compact)."""
+@pytest.mark.parametrize("cap", [1 << 16, 1 << 21])
+def test_map_apply_pass_compiles_for_v5e(one_chip, cap):
+    """The served map's fused mixed-op update pass (XLA bounded-edit
+    merge), at 2^16 slots a shard and at the benchmark's 2^21."""
     from repro.core.batched_map import MapState, _apply_impl
 
-    cap = 1 << 16
     state = MapState(*[jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                        for s, d in (((K, cap + 1), F32), ((K, cap + 1), F32),
                                     ((K,), I32))])
@@ -130,4 +131,20 @@ def test_map_apply_pass_compiles_for_v5e(one_chip):
         state, *[jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                  for s, d in ops],
         key_range=(0.0, 100.0), use_pallas=False, placement=None).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_sketch_apply_pass_compiles_for_v5e(one_chip):
+    """The sketch's fused add pass (XLA insert-only bounded-edit merge)
+    at 2^16 counters a shard."""
+    from repro.core.batched_sketch import SketchState, _apply_impl
+
+    cap = 1 << 16
+    state = SketchState(*[jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                          for s, d in (((K, cap + 1), F32),
+                                       ((K, cap + 1), F32), ((K,), I32))])
+    ops = [((MAP_C,), F32), ((MAP_C,), F32), ((), I32)]
+    compiled = jax.jit(_apply_impl, static_argnames=("use_pallas",)).lower(
+        state, *[jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                 for s, d in ops], use_pallas=False).compile()
     assert "tpu_custom_call" not in compiled.as_text()
