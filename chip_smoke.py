@@ -3,8 +3,8 @@ answer checked against a plain reference.
 
     python chip_smoke.py             # one chip: pq, pc-pallas pq, map,
                                      # graph, decode (qwen2_0_5b)
-    python chip_smoke.py --chips 4   # four chips: pq and map placed on a
-                                     # D=4 mesh vs the stacked layout
+    python chip_smoke.py --chips 4   # four chips: pq, map and graph placed
+                                     # on a D=4 mesh vs the stacked layout
 
 Everything runs in this one process, through ``repro.launch.serve.
 run_serving`` with the ``PCScheduler``; traffic comes from the structure
@@ -328,8 +328,8 @@ def decode_phase(clock, seed):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: only the pq and map phases, placed on a D=4 "
-                         "mesh and compared with the stacked layout")
+                    help="4: only the pq, map and graph phases, placed on "
+                         "a D=4 mesh and compared with the stacked layout")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -356,6 +356,7 @@ def main(argv=None) -> int:
     if args.chips == 4:
         mesh_phase("pq", clock, args.seed, 4)
         mesh_phase("map", clock, args.seed, 4)
+        mesh_phase("graph", clock, args.seed, 4)
     else:
         structure_phase("pq", clock, args.seed, pallas_twin=True)
         structure_phase("map", clock, args.seed)

@@ -1,10 +1,10 @@
 """Device-resident batched union-find (DESIGN.md §16).
 
 The second workload landed through the :mod:`~repro.core.substrate`
-protocol: the graph's ``merge_labels`` fast path (kernels/label_prop)
-already computes exactly the union-find transition — fold a batch of new
-edges into a valid component-min labeling via the CONTRACTED-graph
-fixpoint — so this module only wraps it in the substrate idioms: a
+protocol: the label-propagation step (kernels/label_prop) run on the
+CONTRACTED graph computes exactly the union-find transition — fold a
+batch of new edges into a valid component-min labeling — so this module
+only wraps that fixpoint in the substrate idioms: a
 donated apply pass with an undonated twin, pow2 rounds lowering onto one
 ``lax.scan`` (DESIGN.md §12), transactional snapshot/restore (DESIGN.md
 §15), the async one-fetch contract (DESIGN.md §11), and an atomic
@@ -57,8 +57,7 @@ class UFState(NamedTuple):
 def _contracted_fixpoint(ceu, cev, *, n: int, n_shards: int,
                          use_pallas: bool) -> jax.Array:
     """Component-min relabeling ``p`` of the contracted graph: vertices =
-    current labels, edges = the batch's label pairs (``merge_labels``'s
-    construction).  ``use_pallas`` iterates the ``grid=(K,)`` kernel,
+    current labels, edges = the batch's label pairs.  ``use_pallas`` iterates the ``grid=(K,)`` kernel,
     else the XLA twin — bit-exact per iteration, hence at the fixpoint."""
     if use_pallas:
         step = lambda p: label_step(p, ceu, cev, n_shards=n_shards)

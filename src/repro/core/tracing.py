@@ -5,7 +5,8 @@ but a call: an operator or a benchmark calls ``TRACER.start()``, serves,
 and takes the records with ``TRACER.stop()``.
 
 Off, every site costs one attribute test: :meth:`Tracer.span` returns one
-shared no-op context manager and :meth:`Tracer.stamp` returns 0.  On:
+shared no-op context manager, :meth:`Tracer.stamp` returns 0 and the
+counters keep nothing.  On:
 
 * ``span(name)`` keeps ``(name, t0_ns, t1_ns, cpu_ns)`` in a list of the
   calling thread (wall time from ``perf_counter_ns``; ``cpu_ns`` is the
@@ -13,6 +14,8 @@ shared no-op context manager and :meth:`Tracer.stamp` returns 0.  On:
 * ``batch(...)`` keeps one record per executor pass: the ``step_fn``
   call's start and end, its operation count, and its operations' summed
   waits before it;
+* ``count(name, k)`` adds to a named counter and ``gauge(name, v)`` sets
+  one (a structure's host counters: how many passes took which path);
 * ``anchor()`` writes one ``jax.profiler.TraceAnnotation`` named
   :data:`ANCHOR`, with its index as the stat ``n``, between two
   ``perf_counter_ns`` reads.  Where a profiler session records it, the
@@ -72,6 +75,7 @@ class Tracer:
         self._lists: List[List[Span]] = []
         self._batches: List[Batch] = []
         self._anchors: List[Tuple[int, int]] = []
+        self._counters: Dict[str, int] = {}
 
     def start(self) -> None:
         with self._lock:
@@ -79,18 +83,20 @@ class Tracer:
             self._lists = []
             self._batches = []
             self._anchors = []
+            self._counters = {}
             self.on = True
 
     def stop(self) -> Dict[str, Any]:
         """Turn recording off and return what it kept: ``spans`` (every
-        thread's, by start), ``batches`` (in pass order) and ``anchors``
-        (``(before, after)`` by index)."""
+        thread's, by start), ``batches`` (in pass order), ``anchors``
+        (``(before, after)`` by index) and ``counters`` (by name)."""
         with self._lock:
             self.on = False
             spans = sorted((s for lst in self._lists for s in lst),
                            key=lambda s: s[1])
             return {"spans": spans, "batches": list(self._batches),
-                    "anchors": list(self._anchors)}
+                    "anchors": list(self._anchors),
+                    "counters": dict(self._counters)}
 
     def _thread_list(self) -> List[Span]:
         loc = self._local
@@ -108,6 +114,18 @@ class Tracer:
     def stamp(self) -> int:
         """``perf_counter_ns()`` while on, else 0."""
         return _clock() if self.on else 0
+
+    def count(self, name: str, k: int = 1) -> None:
+        """Add ``k`` to the counter ``name``."""
+        if self.on:
+            with self._lock:
+                self._counters[name] = self._counters.get(name, 0) + k
+
+    def gauge(self, name: str, value: int) -> None:
+        """Set the counter ``name`` to ``value``."""
+        if self.on:
+            with self._lock:
+                self._counters[name] = value
 
     def batch(self, t_start: int, t_end: int, n: int, pending_ns: int,
               handoff_ns: int) -> None:

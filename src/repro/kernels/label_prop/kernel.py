@@ -74,7 +74,7 @@ def _row_to_col(x: jax.Array) -> jax.Array:
 
 
 def _label_step_kernel(labels_ref, eu_ref, ev_ref, out_ref,
-                       *, block: int, e_cap: int, e_chunk: int):
+                       *, block: int, e_cap: int, e_chunk: int, jump: bool):
     k = pl.program_id(0)
     base = pl.multiple_of(k * block, _V_TILE)
     labels = labels_ref[...]                       # (1, n_pad) i32, full row
@@ -93,23 +93,27 @@ def _label_step_kernel(labels_ref, eu_ref, ev_ref, out_ref,
         return jnp.minimum(s, jnp.minimum(cu, cv))
 
     out_ref[...] = jax.lax.fori_loop(0, e_cap // e_chunk, chunk, s)
+    if not jump:
+        return
 
     # pointer jump through the OLD labels (see module docstring), one
     # 128-vertex tile at a time to bound the gather mask
-    def jump(j, _):
+    def jump_tile(j, _):
         o = pl.multiple_of(j * _V_TILE, _V_TILE)
         sj = out_ref[:, pl.ds(o, _V_TILE)]
         g = col_to_row(_gather_i32(labels, _row_to_col(sj)))
         out_ref[:, pl.ds(o, _V_TILE)] = jnp.minimum(sj, g)
         return 0
 
-    jax.lax.fori_loop(0, block // _V_TILE, jump, 0)
+    jax.lax.fori_loop(0, block // _V_TILE, jump_tile, 0)
 
 
 def label_step_sharded_vmem(labels: jax.Array, eu: jax.Array, ev: jax.Array,
                             *, n_shards: int, e_chunk: int,
-                            interpret: bool = False) -> jax.Array:
-    """One scatter-min + pointer-jump iteration as ONE ``grid=(K,)`` kernel.
+                            interpret: bool = False,
+                            jump: bool = True) -> jax.Array:
+    """One scatter-min + pointer-jump iteration as ONE ``grid=(K,)`` kernel
+    (``jump=False``: the scatter-min alone, one hop of propagation).
 
     labels: (n_pad,) i32 with n_pad divisible by ``n_shards`` into blocks
     of a multiple of 128 vertices; eu/ev: (e_cap,) i32 edge endpoints,
@@ -123,7 +127,7 @@ def label_step_sharded_vmem(labels: jax.Array, eu: jax.Array, ev: jax.Array,
     block = n_pad // n_shards
     assert block % _V_TILE == 0 and e_chunk % _V_TILE == 0
     kernel = functools.partial(_label_step_kernel, block=block,
-                               e_cap=e_cap, e_chunk=e_chunk)
+                               e_cap=e_cap, e_chunk=e_chunk, jump=jump)
     out = pl.pallas_call(
         kernel,
         grid=(n_shards,),

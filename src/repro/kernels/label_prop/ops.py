@@ -5,24 +5,22 @@ Three layers:
 * ``label_step`` — one scatter-min + pointer-jump iteration, dispatched as
   the ``grid=(K,)`` Pallas kernel over a K-way vertex partition (padding
   the vertex set to K equal blocks and the edge list to the kernel's
-  streaming chunk size).  ``label_step_xla`` is the bit-exact pure-XLA
-  twin (scatter ``.at[].min`` + gather) used as the CPU/fallback path and
-  by the union-find fast path.
+  streaming chunk size); ``jump=False`` leaves out the pointer jump, one
+  hop of propagation.  ``label_step_xla`` is the bit-exact pure-XLA twin
+  (scatter ``.at[].min`` + gather) used as the CPU/fallback path.
 * ``connected_components`` — the fixpoint loop: iterate the step until the
   labels stop changing.  Labels converge to the component-min id (labels
   only decrease, ``l[x] ≤ x`` is invariant, and the min vertex of every
   component is a fixpoint of both the hook and the jump).
-* ``merge_labels`` — the insert-only *union-find fast path* (DESIGN.md
-  §11): given a valid component labeling and a small batch of new edges,
-  run the fixpoint on the CONTRACTED graph whose vertices are the current
-  labels and whose edges are the label pairs of the new edges, then
-  compose.  O(b log n) work for b new edges instead of the full
-  O(E log n) rebuild — the common case in a read-dominated workload.
+* ``spanning_forest`` — the same labels by one hop a step (no jump), so a
+  vertex's last step of change is its hop distance to its component's
+  min, plus the breadth-first spanning forest those distances define:
+  the device graph's rebuild from scratch (``core/device_graph.py``).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,11 +56,13 @@ def label_step_xla(labels: jax.Array, eu: jax.Array,
     return jnp.minimum(s, labels[s])
 
 
-@functools.partial(jax.jit, static_argnames=("n_shards", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_shards", "interpret",
+                                             "jump"))
 def label_step(labels: jax.Array, eu: jax.Array, ev: jax.Array, *,
-               n_shards: int = 1,
-               interpret: Optional[bool] = None) -> jax.Array:
-    """One label-propagation iteration via the ``grid=(K,)`` kernel.
+               n_shards: int = 1, interpret: Optional[bool] = None,
+               jump: bool = True) -> jax.Array:
+    """One label-propagation iteration via the ``grid=(K,)`` kernel
+    (``jump=False``: the scatter-min alone).
 
     labels: (n,) i32; eu/ev: (E,) i32 endpoints with invalid/padding edges
     sanitized to (0, 0) self-loops.  Pads the vertex set to ``n_shards``
@@ -84,7 +84,7 @@ def label_step(labels: jax.Array, eu: jax.Array, ev: jax.Array, *,
     ev_p = jnp.zeros((e_pad,), jnp.int32).at[:e].set(ev.astype(jnp.int32))
     out = label_step_sharded_vmem(labels_p, eu_p, ev_p, n_shards=n_shards,
                                   e_chunk=min(_E_CHUNK, e_pad),
-                                  interpret=interpret)
+                                  interpret=interpret, jump=jump)
     return out[:n]
 
 
@@ -101,63 +101,18 @@ def _fixpoint(step_fn, labels0: jax.Array) -> jax.Array:
     return l
 
 
-def _cc_collective(eu: jax.Array, ev: jax.Array, *, n: int, placement):
-    """Mesh-parallel fixpoint (DESIGN.md §18): EDGES split across the D
-    devices, one full label table per device.
-
-    Each iteration every device scatter-mins its edge block into its
-    label copy, a ``pmin`` merges the D partial tables, and the pointer
-    jump runs replicated.  Per-iteration this equals
-    :func:`label_step_xla` element-wise — min is associative and
-    commutative, so min-reducing per-block scatters then pmin-reducing
-    across blocks is the same table as one global scatter-min — hence
-    the fixpoint (and its iteration count) is bit-identical to the
-    stacked trace.  The whole while_loop lives INSIDE one shard_map
-    body: D devices, one program, no per-iteration re-dispatch.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    ax = placement.axis
-    d = placement.n_devices
-    (e,) = eu.shape
-    e_pad = ceil_to(max(e, 1), d)
-    # pad with (0, 0) self-loops — the sanitized-edge no-op
-    eu_p = jnp.zeros((e_pad,), jnp.int32).at[:e].set(eu.astype(jnp.int32))
-    ev_p = jnp.zeros((e_pad,), jnp.int32).at[:e].set(ev.astype(jnp.int32))
-
-    def body(eu_blk, ev_blk):
-        def step(l):
-            m = jnp.minimum(l[eu_blk], l[ev_blk])
-            s = l.at[eu_blk].min(m).at[ev_blk].min(m)
-            s = jax.lax.pmin(s, ax)
-            return jnp.minimum(s, l[s])
-
-        return _fixpoint(step, jnp.arange(n, dtype=jnp.int32))
-
-    fn = jax.shard_map(body, mesh=placement.mesh,
-                       in_specs=(P(ax), P(ax)), out_specs=P(),
-                       check_vma=False)
-    return fn(eu_p, ev_p)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("n", "n_shards", "use_pallas",
-                                    "interpret", "placement"))
+                                    "interpret"))
 def connected_components(eu: jax.Array, ev: jax.Array, *, n: int,
                          n_shards: int = 1, use_pallas: bool = False,
-                         interpret: Optional[bool] = None,
-                         placement=None) -> jax.Array:
+                         interpret: Optional[bool] = None) -> jax.Array:
     """Component-min labels of the graph on [0, n) with the given edges.
 
     eu/ev: (E,) i32 endpoints, invalid slots sanitized to (0, 0).
     ``use_pallas`` iterates the shard-grid kernel; otherwise the XLA twin.
     Both paths are bit-exact per iteration, hence at the fixpoint.
-    ``placement`` (static): a ``MeshPlacement`` runs the edge-partitioned
-    collective fixpoint (:func:`_cc_collective`) instead; ``None``/
-    stacked keeps the single-device trace.
     """
-    if placement is not None and placement.is_mesh:
-        return _cc_collective(eu, ev, n=n, placement=placement)
     labels0 = jnp.arange(n, dtype=jnp.int32)
     if use_pallas:
         step = functools.partial(label_step, eu=eu, ev=ev,
@@ -166,20 +121,75 @@ def connected_components(eu: jax.Array, ev: jax.Array, *, n: int,
     return _fixpoint(lambda l: label_step_xla(l, eu, ev), labels0)
 
 
-@functools.partial(jax.jit, static_argnames=("n",))
-def merge_labels(labels: jax.Array, eu: jax.Array, ev: jax.Array, *,
-                 n: int) -> jax.Array:
-    """Union-find fast path: fold a batch of NEW edges into valid labels.
+def _hop_xla(l: jax.Array, eu: jax.Array, ev: jax.Array) -> jax.Array:
+    """One hop of min-label propagation: the scatter-min, no jump."""
+    m = jnp.minimum(l[eu], l[ev])
+    return l.at[eu].min(m).at[ev].min(m)
 
-    ``labels`` must be a component-min labeling of the graph WITHOUT the
-    new edges.  Runs the fixpoint on the contracted graph (vertices =
-    current labels, edges = label pairs of the new edges — b edges, not
-    E) and composes: new_label[x] = p[labels[x]].  Invalid edge slots must
-    be (0, 0) self-loops (a no-op on the contracted graph too).
+
+def spanning_forest(eu: jax.Array, ev: jax.Array, *, n: int,
+                    n_shards: int = 1, use_pallas: bool = False,
+                    interpret: Optional[bool] = None, placement=None
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Component-min labels plus a breadth-first spanning forest.
+
+    Min-label propagation one hop a step: after step k a vertex holds the
+    least id within k hops, so the step at which its label last fell is
+    its hop distance ``d`` to its component's min.  Every other vertex
+    has an edge to a vertex one hop nearer; the least index of those
+    edges is its parent edge.  Parents point strictly nearer the min, so
+    the parent edges form a spanning forest rooted at the mins.
+
+    eu/ev: (E,) i32 endpoints, invalid slots sanitized to (0, 0) (never a
+    parent: both ends are one vertex).  Returns ``(labels, parent,
+    iterations)``: ``parent`` (n,) i32 is an index into eu/ev, or E at a
+    component's min; ``iterations`` counts the steps, the last of which
+    changes nothing.  ``use_pallas`` runs each step as the ``grid=(K,)``
+    kernel without its jump.  ``placement`` (static): a ``MeshPlacement``
+    splits the edges across its D devices and ``pmin``-merges the tables
+    each step (DESIGN.md §18), the same tables as one device's, step for
+    step, because min is associative and commutative.
     """
-    labels = labels.astype(jnp.int32)
-    ceu = labels[eu.astype(jnp.int32)]
-    cev = labels[ev.astype(jnp.int32)]
-    p0 = jnp.arange(n, dtype=jnp.int32)
-    p = _fixpoint(lambda p: label_step_xla(p, ceu, cev), p0)
-    return p[labels]
+    (e,) = eu.shape
+
+    def forest(eu_blk, ev_blk, base, merge, hop):
+        def body(st):
+            l, d, k, _ = st
+            s = merge(hop(l, eu_blk, ev_blk))
+            fell = s < l
+            return s, jnp.where(fell, k + 1, d), k + 1, jnp.any(fell)
+
+        l, d, k, _ = jax.lax.while_loop(
+            lambda st: st[3], body,
+            (jnp.arange(n, dtype=jnp.int32), jnp.zeros((n,), jnp.int32),
+             jnp.int32(0), jnp.bool_(True)))
+        du, dv = d[eu_blk], d[ev_blk]
+        idx = base + jnp.arange(eu_blk.shape[0], dtype=jnp.int32)
+        parent = merge(jnp.full((n,), e, jnp.int32)
+                       .at[eu_blk].min(jnp.where(du == dv + 1, idx, e))
+                       .at[ev_blk].min(jnp.where(dv == du + 1, idx, e)))
+        return l, parent, k
+
+    if placement is None or not placement.is_mesh:
+        hop = _hop_xla
+        if use_pallas:
+            hop = functools.partial(label_step, n_shards=n_shards,
+                                    interpret=interpret, jump=False)
+        return forest(eu, ev, 0, lambda t: t, hop)
+    from jax.sharding import PartitionSpec as P
+
+    ax = placement.axis
+    d = placement.n_devices
+    e_pad = ceil_to(max(e, 1), d)
+    # pad with (0, 0) self-loops — never a parent
+    eu_p = jnp.zeros((e_pad,), jnp.int32).at[:e].set(eu.astype(jnp.int32))
+    ev_p = jnp.zeros((e_pad,), jnp.int32).at[:e].set(ev.astype(jnp.int32))
+
+    def body(eu_blk, ev_blk):
+        base = jax.lax.axis_index(ax) * (e_pad // d)
+        return forest(eu_blk, ev_blk, base,
+                      lambda t: jax.lax.pmin(t, ax), _hop_xla)
+
+    fn = jax.shard_map(body, mesh=placement.mesh, in_specs=(P(ax), P(ax)),
+                       out_specs=(P(), P(), P()), check_vma=False)
+    return fn(eu_p, ev_p)

@@ -154,31 +154,41 @@ def test_capacity_refusal_is_atomic():
 # union-find fast path (satellite: counting regression)
 # ---------------------------------------------------------------------------
 def test_insert_only_batches_take_union_find_fast_path():
-    """Insert-only traffic must NOT trigger full label-prop rebuilds —
-    the device-side rebuild counter (the fused-pass analogue of PR 2's
-    ``_host_fetch`` counting hook) stays flat; a single netted-out delete
-    invalidates the labeling exactly once."""
+    """Insert-only traffic must NOT trigger label-prop rebuilds — the
+    device-side counters (the fused-pass analogue of the
+    ``_host_fetch`` counting hook) show merges only; a delete outside the
+    forest changes nothing, and a netted-out forest edge is repaired
+    exactly once, without a rebuild from scratch."""
     g = _mk(n=50)
     base = g.full_rebuilds()
     for i in range(6):
         g.insert_batch([(i, i + 1), (i + 10, i + 11)])
         assert g.connected(0, i + 1) is True
     assert g.full_rebuilds() == base     # merges only — the fast path
+    assert g.refresh_counts()["merge"] == 6
     # a FAILED delete must not invalidate either
     assert g.delete(40, 41) is False
     assert g.connected(0, 1) is True
     assert g.full_rebuilds() == base
-    # one successful delete → exactly one full rebuild on the next read
+    # a cycle edge is no forest edge: deleting it repairs nothing
+    assert g.insert(0, 2) is True
+    assert g.connected(0, 2) is True
+    assert g.delete(0, 2) is True
+    assert g.connected(0, 2) is True
+    assert g.refresh_counts()["repair"] == 0
+    # one successful forest-edge delete → exactly one repair
     assert g.delete(2, 3) is True
     assert g.connected(0, 2) is True
     assert g.connected(0, 3) is False
-    assert g.full_rebuilds() == base + 1
+    counts = g.refresh_counts()
+    assert counts["repair"] == 1 and counts["overflow"] == 0
+    assert g.full_rebuilds() == base
 
 
 def test_fast_path_labels_equal_full_rebuild_labels():
-    """The contracted-graph merge converges to the same component-min
-    labeling as a from-scratch rebuild (canonical labels, not just equal
-    partitions)."""
+    """The incremental merges give the same partition as a from-scratch
+    rebuild, with canonical labels: each vertex's label is the root of
+    its forest tree, and the forest edges span the components."""
     from repro.kernels.label_prop.ref import components_reference
 
     rng = np.random.default_rng(5)
@@ -189,7 +199,18 @@ def test_fast_path_labels_equal_full_rebuild_labels():
         g.insert_batch(edges)
         g.connected(0, 1)                # merges happen incrementally
     want = components_reference(40, g.edges())
-    np.testing.assert_array_equal(np.asarray(g.state.labels), want)
+    labels = np.asarray(g.state.labels)
+    par = np.asarray(g.state.par)
+    for x in range(40):                  # same partition, root labels
+        r = x
+        while par[r] != r:
+            r = par[r]
+        assert labels[x] == r
+        assert (labels == labels[x]).tolist() == (want == want[x]).tolist()
+    eu, ev = np.asarray(g.state.eu), np.asarray(g.state.ev)
+    in_f, valid = np.asarray(g.state.in_f), np.asarray(g.state.valid)
+    assert np.all(valid[in_f])
+    assert in_f.sum() == 40 - len(np.unique(want))
 
 
 def test_pending_overflow_falls_back_to_full_rebuild():

@@ -17,7 +17,7 @@ from repro.kernels.heap_kmin.ref import k_smallest_reference
 from repro.kernels.heap_sift import sift_wavefront, sift_wavefront_sharded
 from repro.kernels.heap_sift.ref import sift_wavefront_reference
 from repro.kernels.label_prop import (connected_components, label_step,
-                                      label_step_xla, merge_labels)
+                                      label_step_xla, spanning_forest)
 from repro.kernels.label_prop.ref import (components_reference,
                                           label_step_reference)
 from repro.kernels.linear_scan import rglru_scan, rwkv6_scan
@@ -346,21 +346,58 @@ def test_connected_components_pallas_and_xla_bit_exact():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_merge_labels_union_find_fast_path():
-    """Folding new edges into a valid labeling equals a full rebuild."""
-    rng = np.random.default_rng(19)
-    n = 45
-    eu, ev = _random_edges(rng, n, 60)
-    base = connected_components(jnp.asarray(eu[:40]), jnp.asarray(ev[:40]),
-                                n=n)
-    merged = merge_labels(base, jnp.asarray(eu[40:]), jnp.asarray(ev[40:]),
-                          n=n)
-    np.testing.assert_array_equal(
-        np.asarray(merged), components_reference(n, zip(eu, ev)))
-    # empty merge is the identity
-    noop = merge_labels(base, jnp.zeros((4,), jnp.int32),
-                        jnp.zeros((4,), jnp.int32), n=n)
-    np.testing.assert_array_equal(np.asarray(noop), np.asarray(base))
+@pytest.mark.parametrize("n_shards", [1, 4])
+def test_label_step_without_jump_is_one_hop(n_shards):
+    """``jump=False``: the kernel's scatter-min alone, one hop of
+    min-label propagation, equal to the numpy scatter-min."""
+    rng = np.random.default_rng(17)
+    n, e = 70, 60
+    eu, ev = _random_edges(rng, n, e)
+    labels = rng.permutation(n).astype(np.int32)
+    m = np.minimum(labels[eu], labels[ev])
+    want = labels.copy()
+    np.minimum.at(want, eu, m)
+    np.minimum.at(want, ev, m)
+    got = label_step(jnp.asarray(labels), jnp.asarray(eu), jnp.asarray(ev),
+                     n_shards=n_shards, jump=False)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("seed,n,e,use_pallas", [
+    (3, 60, 40, False), (4, 60, 90, False), (5, 200, 260, False),
+    (6, 9, 0, False), (4, 60, 90, True)])
+def test_spanning_forest_is_breadth_first(seed, n, e, use_pallas):
+    """Labels equal the component mins; each other vertex's parent edge
+    leads one hop nearer its component's min, so the parent edges are
+    n minus the component count distinct edges spanning every component;
+    the step count is the largest hop distance plus the step that finds
+    nothing."""
+    import scipy.sparse as sp
+    from scipy.sparse.csgraph import shortest_path
+
+    rng = np.random.default_rng(seed)
+    eu, ev = (_random_edges(rng, n, e) if e
+              else (np.zeros(1, np.int32), np.zeros(1, np.int32)))
+    lab, parent, steps = spanning_forest(jnp.asarray(eu), jnp.asarray(ev),
+                                         n=n, n_shards=4,
+                                         use_pallas=use_pallas)
+    lab, parent = np.asarray(lab), np.asarray(parent)
+    want = components_reference(n, zip(eu, ev))
+    np.testing.assert_array_equal(lab, want)
+    roots = np.unique(want)
+    adj = sp.coo_matrix((np.ones(len(eu)), (eu, ev)), shape=(n, n))
+    hops = shortest_path(adj, unweighted=True, directed=False,
+                         indices=roots)
+    d = hops[np.searchsorted(roots, want), np.arange(n)]
+    assert int(steps) == int(d.max()) + 1
+    is_root = want == np.arange(n)
+    assert np.all(parent[is_root] == len(eu))
+    kids = np.flatnonzero(~is_root)
+    pe = parent[kids]
+    assert len(set(pe.tolist())) == len(kids)
+    assert np.all((eu[pe] == kids) | (ev[pe] == kids))
+    other = np.where(eu[pe] == kids, ev[pe], eu[pe])
+    np.testing.assert_array_equal(d[other], d[kids] - 1)
 
 
 # ---------------------------------------------------------------------------

@@ -39,9 +39,12 @@ def test_off_returns_one_shared_noop_and_records_nothing():
         pass
     assert tr.stamp() == 0
     tr.batch(1, 2, 3, 4, 5)
+    tr.count("a")
+    tr.gauge("b", 7)
     tr.anchor()
     tr.start()
-    assert tr.stop() == {"spans": [], "batches": [], "anchors": []}
+    assert tr.stop() == {"spans": [], "batches": [], "anchors": [],
+                         "counters": {}}
 
 
 def test_spans_nest_and_every_thread_comes_back():
@@ -188,3 +191,157 @@ def test_anchor_lands_in_a_cpu_profiler_trace(tmp_path):
     assert width < 100_000
     for off, w in pairs:
         assert abs(off - best) <= w + width
+
+
+# ---------------------------------------------------------------------------
+# the graph's spans and counters
+# ---------------------------------------------------------------------------
+GRAPH_SPANS = ("graph.prep", "graph.dispatch", "graph.fetch",
+               "graph.convert")
+
+
+def _graph_executor(edges, n):
+    return StructureExecutor(substrate.get("graph"), n=n,
+                             edge_capacity=4096, c_max=16, n_shards=2,
+                             edges=edges)
+
+
+def _kron(scale, seed):
+    from repro.core.seq_graph import canonical_edges, kronecker_edges
+
+    n = 1 << scale
+    return canonical_edges(kronecker_edges(
+        scale, 16 * n, np.random.default_rng(seed)), n), n
+
+
+def _mixed_batch(rng, distinct, n, k=12):
+    reqs = []
+    for i in range(k):
+        r = rng.random()
+        if r < 0.25:
+            e = tuple(int(x) for x in distinct[rng.integers(len(distinct))])
+            reqs.append({"method": "delete", "input": e})
+        elif r < 0.45:
+            reqs.append({"method": "insert", "input": tuple(
+                int(x) for x in rng.integers(0, n, 2))})
+        else:
+            reqs.append({"method": "connected", "input": tuple(
+                int(x) for x in rng.integers(0, n, 2))})
+    return reqs
+
+
+def test_graph_spans_come_once_a_pass(stop_tracer):
+    """Each pass of updates and reads makes one span of each graph stage
+    per call into the graph: prep and dispatch for the update and for
+    the read, the read's one fetch, and its conversion."""
+    distinct, n = _kron(7, 3)
+    ex = _graph_executor(distinct, n)
+    rng = np.random.default_rng(4)
+    ex(_mixed_batch(rng, distinct, n))         # compile outside the count
+    windows = []
+    TRACER.start()
+    for _ in range(10):
+        reqs = _mixed_batch(rng, distinct, n)
+        t0 = time.perf_counter_ns()
+        ex(reqs)
+        windows.append((t0, time.perf_counter_ns(),
+                        any(r["method"] != "connected" for r in reqs)))
+    rec = TRACER.stop()
+    for t0, t1, has_update in windows:
+        names = [s[0] for s in rec["spans"] if t0 <= s[1] and s[2] <= t1]
+        calls = 2 if has_update else 1
+        assert names.count("graph.prep") == calls
+        assert names.count("graph.dispatch") == calls
+        assert names.count("graph.fetch") == 1
+        assert names.count("graph.convert") >= 1
+    assert {s[0] for s in rec["spans"]} == set(GRAPH_SPANS)
+
+
+def test_graph_counters_match_the_device(stop_tracer):
+    """The host counters that ride each read's fetch add up to the
+    device's own refresh counts; the lean reads are the rest."""
+    distinct, n = _kron(8, 5)
+    ex = _graph_executor(distinct, n)
+    before = ex.ds.refresh_counts()
+    TRACER.start()
+    rng = np.random.default_rng(6)
+    n_reads = 0
+    for i in range(40):
+        reqs = (_mixed_batch(rng, distinct, n) if i % 3 else
+                [{"method": "connected", "input": (1, 2)}])
+        n_reads += any(r["method"] == "connected" for r in reqs)
+        ex(reqs)
+    rec = TRACER.stop()
+    after = ex.ds.refresh_counts()
+    c = rec["counters"]
+    for b in ("keep", "merge", "repair", "scratch"):
+        assert c.get(f"graph.refresh.{b}", 0) == after[b] - before[b], b
+    assert c.get("graph.repair.overflow", 0) == (after["overflow"]
+                                                 - before["overflow"])
+    fused = sum(after[b] - before[b]
+                for b in ("keep", "merge", "repair", "scratch"))
+    assert fused + c["graph.refresh.lean"] == n_reads
+    assert after["scratch"] == 1 and after["repair"] > 0
+
+
+def test_graph_step_counts_match_numpy_counts(stop_tracer):
+    """The scratch fixpoint counts the largest hop distance from a
+    component's least vertex, plus the step that changes nothing; a
+    repair counts the levels of the forest below its cut, plus the one
+    that finds nothing."""
+    from repro.core.device_graph import DeviceGraph
+
+    distinct, n = _kron(8, 7)
+    adj = [[] for _ in range(n)]
+    for u, v in distinct.tolist():
+        adj[u].append(v)
+        adj[v].append(u)
+    depth = np.full(n, -1)
+    for r in range(n):
+        if depth[r] >= 0:
+            continue
+        depth[r], front, d = 0, [r], 0
+        while front:
+            d += 1
+            nxt = sorted({w for x in front for w in adj[x] if depth[w] < 0})
+            for w in nxt:
+                depth[w] = d
+            front = nxt
+    TRACER.start()
+    g = DeviceGraph(n, edge_capacity=4096, c_max=16, edges=distinct)
+    g.connected(0, 1)
+    assert TRACER.stop()["counters"]["graph.refresh.steps"] == \
+        int(depth.max()) + 1
+    # a path 0-1-...-9: cutting (4, 5) leaves 5..9, four levels below 5
+    TRACER.start()
+    p = DeviceGraph(10, edge_capacity=64, c_max=4,
+                    edges=[(i, i + 1) for i in range(9)])
+    p.connected(0, 9)
+    p.delete(4, 5)
+    assert p.connected(0, 9) is False
+    c = TRACER.stop()["counters"]
+    assert c["graph.refresh.repair"] == 1
+    assert c["graph.refresh.steps"] == 5
+
+
+def test_graph_pass_makes_one_host_fetch(monkeypatch):
+    """Updates and reads of one pass still cost one blocking fetch, the
+    refresh counters included."""
+    import repro.core.device_graph as dg
+
+    distinct, n = _kron(7, 9)
+    ex = _graph_executor(distinct, n)
+    rng = np.random.default_rng(10)
+    ex(_mixed_batch(rng, distinct, n))
+    fetches = []
+    real = dg._host_fetch
+    monkeypatch.setattr(dg, "_host_fetch",
+                        lambda t: fetches.append(1) or real(t))
+    for _ in range(5):
+        fetches.clear()
+        reqs = _mixed_batch(rng, distinct, n)
+        reqs.append({"method": "delete", "input": tuple(
+            int(x) for x in distinct[0])})
+        reqs.append({"method": "connected", "input": (0, 1)})
+        ex(reqs)
+        assert fetches == [1]
