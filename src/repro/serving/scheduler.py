@@ -20,9 +20,13 @@ production async engine:
   merge, all as vmapped device programs) and hands the chosen batch to the
   device;
 * the combiner is **pipelined** against the device: while device pass N is
-  in flight, the combiner is already collecting and ordering pass N+1
-  (a depth-1 handoff queue), so host-side ordering cost hides behind
-  device compute;
+  in flight, the combiner keeps collecting and ordering, and appends what
+  it chooses to the **waiting batch** — the newest batch the device
+  thread has not taken yet, topped up to ``max_batch`` (overflow opens the
+  next one).  The device thread takes the oldest waiting batch as soon as
+  it is free, so every request chosen during pass N rides pass N+1 rather
+  than queueing behind a batch frozen at the size it had when handed
+  over; host-side ordering cost still hides behind device compute;
 * PQ device programs are **sync-free** (DESIGN.md §10): publishing new
   keys uses ``apply_async`` — the insert dispatch returns immediately with
   the result left on device — and the extraction apply performs exactly
@@ -41,8 +45,10 @@ production async engine:
   one device batch, the combiner asks the PQ for R = ⌈backlog/max_batch⌉
   (capped at ``rounds_cap``) extraction rounds in ONE fused
   ``apply_rounds`` dispatch — publish round + R extract rounds all run
-  inside a single donated ``lax.scan`` program, and the R chosen batches
-  are handed to the device loop back-to-back.
+  inside a single donated ``lax.scan`` program, and the chosen requests
+  join the waiting batches back-to-back.  Back-pressure: the combiner
+  starts an ordering pass only while at most one batch waits, so at most
+  that batch plus one pass's rounds are ever chosen ahead of the device.
 
 ``SerialScheduler`` is the fine-grained baseline: every request dispatches
 its own device program under a plain mutex (the "single global lock, no
@@ -51,7 +57,7 @@ combining" analogue) — the benchmark compares the two (EXPERIMENTS §Paper).
 from __future__ import annotations
 
 import heapq
-import queue
+import itertools
 import threading
 from collections import deque
 from concurrent.futures import Future
@@ -66,8 +72,6 @@ from repro.core.faults import (CircuitBreaker, DispatchGuard, FaultPlan,
                                InjectedCombinerKill)
 from repro.core.sharded_pq import ShardedBatchedPQ, host_key
 from repro.core.tracing import TRACER
-
-_SENTINEL = object()
 
 
 def _fail_future(f: Future, exc: BaseException) -> None:
@@ -124,9 +128,10 @@ class PCScheduler:
         batched data structure inside the serving layer.
       pq_capacity: per-shard heap capacity of the deadline PQ.
       n_shards: shard count K of the deadline PQ.
-      pipeline: overlap combiner-side collection/ordering of pass N+1 with
-        the in-flight device step of pass N (depth-1 handoff).  False runs
-        the device step inline on the combiner thread (debug mode).
+      pipeline: overlap combiner-side collection/ordering with the
+        in-flight device step, topping up the waiting batch until the
+        device thread takes it.  False runs the device step inline on the
+        combiner thread (debug mode).
       pq_use_pallas: run the deadline PQ's combining passes through the
         shard-grid Pallas kernels (DESIGN.md §10).
       pq_donate: zero-copy (donated) PQ dispatch (default); False is the
@@ -219,7 +224,8 @@ class PCScheduler:
                     self.router.attach_breaker(t, self.breaker)
         self._backlog: Deque[_Entry] = deque()   # FIFO-mode leftovers
         self._pending: Deque[_Entry] = deque()   # publication buffer
-        self._cond = threading.Condition()
+        lock = threading.RLock()
+        self._cond = threading.Condition(lock)
         self._closed = False
         # instrumentation
         self.batches: List[int] = []
@@ -227,8 +233,15 @@ class PCScheduler:
         self.eliminated = 0            # requests served without PQ work
         self.pq_dispatches = 0         # fused PQ programs dispatched
         self.pq_rounds = 0             # combining rounds those carried
+        self.topped_up = 0             # requests that joined a waiting batch
 
-        self._handoff: "queue.Queue[Any]" = queue.Queue(maxsize=1)
+        # chosen batches the device thread has not taken yet, oldest
+        # first; only the newest can have room.  Guarded by the lock; the
+        # device thread waits on _ready, a second condition on it, so a
+        # submit's notify() always reaches the combiner.
+        self._waiting: Deque[List[_Entry]] = deque()
+        self._ready = threading.Condition(lock)
+        self._device_stop = False     # close(): exit once _waiting drains
         self._combiner = threading.Thread(
             target=self._combiner_loop, name="pc-combiner", daemon=True)
         self._device: Optional[threading.Thread] = None
@@ -289,7 +302,6 @@ class PCScheduler:
         its caller hanging.  A concurrent second ``close`` waits for the
         shutdown to complete instead of returning early."""
         with self._cond:
-            first = not self._closed
             self._closed = True
             self._cond.notify_all()
         if self._supervisor is not None:
@@ -302,8 +314,11 @@ class PCScheduler:
             if c is self._combiner:
                 break
         if self._device is not None:
-            if first:
-                self._handoff.put(_SENTINEL)
+            # the combiner is gone, so nothing joins _waiting any more:
+            # the device thread serves every waiting batch, then exits
+            with self._cond:
+                self._device_stop = True
+                self._ready.notify_all()
             self._device.join()
         # an in-flight device step must finish and resolve its futures
         # BEFORE the doomed-future sweep: close() must never fail a
@@ -319,6 +334,9 @@ class PCScheduler:
             doomed = list(self._pending) + list(self._backlog)
             self._pending.clear()
             self._backlog.clear()
+            # empty unless the device thread died before serving them
+            doomed.extend(itertools.chain.from_iterable(self._waiting))
+            self._waiting.clear()
             if self.use_pq:
                 for bucket in self._table.values():
                     doomed.extend(bucket)
@@ -350,12 +368,16 @@ class PCScheduler:
     def _combiner_loop(self) -> None:
         while True:
             with self._cond:
-                while (not self._closed and not self._pending
-                       and not self._has_leftovers()):
+                while True:
+                    work = bool(self._pending) or self._has_leftovers()
+                    if self._closed and not work:
+                        return
+                    # back-pressure: order only while at most one batch
+                    # waits, so no more than it and one pass's rounds
+                    # are ever chosen ahead of the device
+                    if work and len(self._waiting) <= 1:
+                        break
                     self._cond.wait()
-                if (self._closed and not self._pending
-                        and not self._has_leftovers()):
-                    return
                 new = list(self._pending)
                 self._pending.clear()
             if self.fault_plan is not None:
@@ -383,15 +405,39 @@ class PCScheduler:
                 for chosen in chosen_rounds:
                     for ent in chosen:
                         ent.t_chosen = t_chosen
+            if self.pipeline:
+                self._hand_off(list(itertools.chain.from_iterable(
+                    chosen_rounds)))
+                continue
             for chosen in chosen_rounds:
                 self.passes += 1
                 self.batches.append(len(chosen))
-                if self.pipeline:
-                    self._handoff.put(chosen)  # blocks at pipeline depth 1
-                else:
-                    self._run_batch(chosen)
+                self._run_batch(chosen)
+
+    def _hand_off(self, chosen: List[_Entry]) -> None:
+        """Append one ordering pass's chosen entries, in order, to the
+        waiting batches: first the room left in the newest one the device
+        thread has not taken, then new batches of ≤ ``max_batch``."""
+        if not chosen:
+            return
+        with self._cond:
+            topped = 0
+            if self._waiting:
+                newest = self._waiting[-1]
+                topped = min(self.max_batch - len(newest), len(chosen))
+                newest.extend(chosen[:topped])
+            for i in range(topped, len(chosen), self.max_batch):
+                self._waiting.append(chosen[i : i + self.max_batch])
+            self.topped_up += topped
+            self._ready.notify_all()
+        if topped:
+            TRACER.count("combiner.topup", topped)
 
     def _abort_pending(self, new: List[_Entry], exc: BaseException) -> None:
+        """Fail ``new`` and every unchosen leftover.  Waiting batches are
+        left to the device thread: earlier passes chose them, and serving
+        them needs none of the ordering state the failure may have left
+        inconsistent."""
         doomed = list(new) + list(self._backlog)
         self._backlog.clear()
         if self.use_pq:
@@ -427,7 +473,9 @@ class PCScheduler:
         staging pool), deduped by per-entry epoch id, and replayed in
         submission order.  Entries whose future already resolved (e.g. an
         in-flight device step finished while the combiner was down) are
-        skipped — a request is never applied twice."""
+        skipped — a request is never applied twice.  Waiting batches stay
+        where they are: the device thread still serves them, so their
+        epochs count as seen and none of their entries is replayed."""
         with self._cond:
             if self._closed or self._combiner is not dead:
                 return
@@ -447,7 +495,7 @@ class PCScheduler:
                 # _pq_ctor carries the dispatch guard, so the rebuilt PQ
                 # stays transactional under the active fault plan
                 self._pq = ShardedBatchedPQ(**self._pq_ctor)
-            seen: set = set()
+            seen = {ent.epoch for batch in self._waiting for ent in batch}
             requeue: List[_Entry] = []
             for ent in sorted(entries, key=lambda e: e.epoch):
                 if ent.epoch in seen or ent.future.done():
@@ -614,10 +662,16 @@ class PCScheduler:
     # -- device side ---------------------------------------------------------
     def _device_loop(self) -> None:
         while True:
-            with TRACER.span("scheduler.wait_batch"):
-                batch = self._handoff.get()
-            if batch is _SENTINEL:
-                return
+            with TRACER.span("scheduler.wait_batch"), self._cond:
+                while not self._waiting and not self._device_stop:
+                    self._ready.wait()
+                if not self._waiting:
+                    return
+                # once taken, the batch leaves _waiting: nothing joins it
+                batch = self._waiting.popleft()
+                self.passes += 1
+                self.batches.append(len(batch))
+                self._cond.notify_all()     # lifts the back-pressure
             self._run_batch(batch)
 
     def _run_batch(self, batch: List[_Entry]) -> None:

@@ -31,6 +31,8 @@ from repro.core.pc_pq import pc_sharded_priority_queue
 from repro.core.sharded_pq import ShardedBatchedPQ
 from repro.serving.scheduler import PCScheduler
 
+from gated_step import GatedStep, publish_together, wait_waiting
+
 pytestmark = pytest.mark.faults
 
 _NOSLEEP = dict(sleep=lambda s: None)
@@ -412,6 +414,30 @@ def test_scheduler_supervisor_recovers_exactly_once():
     assert Counter(served) == Counter(range(24))   # zero lost, zero dup
     assert s.takeovers >= 1
     assert s.fault_counters()["combiner_kills"] == 1
+
+def test_scheduler_takeover_with_a_waiting_batch_serves_each_once():
+    """The combiner dies while a batch waits for the device: the waiting
+    batch is served by the device thread, the killed pass's requests are
+    replayed, and every request is applied exactly once."""
+    plan = FaultPlan(0, kill_combiner_at_pass=3)
+    step = GatedStep()
+    s = PCScheduler(step, max_batch=4, n_shards=2, fault_plan=plan)
+    f0 = s.submit_async(0)
+    assert step.entered.wait(10)            # pass 1 holds the device
+    futs = publish_together(s, [1, 2, 3])   # pass 2: the waiting batch
+    assert wait_waiting(s, 3) == [[1, 2, 3]]
+    futs += publish_together(s, [4, 5, 6])  # pass 3 dies; pass 4 replays
+    assert wait_waiting(s, 6) == [[1, 2, 3, 4], [5, 6]]
+    step.release()
+    assert [f.result(timeout=30) for f in [f0] + futs] == [
+        2 * i for i in range(7)]
+    s.close()
+    served = [x for rows in step.received for x in rows]
+    assert Counter(served) == Counter(range(7))   # zero lost, zero dup
+    assert step.received == [[0], [1, 2, 3, 4], [5, 6]]
+    assert s.takeovers == 1 and s.fault_counters()["combiner_kills"] == 1
+    assert s.topped_up == 1
+
 
 def test_scheduler_guarded_pq_survives_dispatch_faults():
     plan = FaultPlan(1, dispatch_fail_rate=0.9, max_dispatch_failures=6)

@@ -2,6 +2,7 @@
 import json
 import os
 import shutil
+import sys
 import threading
 import time
 
@@ -15,6 +16,8 @@ from repro.checkpoint import CheckpointManager, latest_step, \
 from repro.data import make_pipeline
 from repro.launch.train import StragglerWatchdog
 from repro.serving import PCScheduler, SerialScheduler
+
+from gated_step import GatedStep, publish_together, wait_waiting
 
 
 # ---------------------------------------------------------------------------
@@ -226,6 +229,129 @@ def test_async_scheduler_drains_on_close():
     assert [f.result(timeout=5) for f in fs] == [i + 1 for i in range(13)]
     with pytest.raises(RuntimeError):
         sch.submit_async(0)
+
+
+def test_waiting_batch_is_topped_up_to_max_batch():
+    """Requests chosen while a batch waits join it up to max_batch; the
+    rest open the next batch."""
+    step = GatedStep()
+    sch = PCScheduler(step, max_batch=4)
+    f0 = sch.submit_async(0)
+    assert step.entered.wait(10)            # pass 1 holds the device
+    futs = publish_together(sch, [1, 2])
+    assert wait_waiting(sch, 2) == [[1, 2]]
+    futs += publish_together(sch, [3, 4, 5])
+    assert wait_waiting(sch, 5) == [[1, 2, 3, 4], [5]]
+    step.release()
+    assert [f.result(timeout=10) for f in [f0] + futs] == [0, 2, 4, 6, 8,
+                                                           10]
+    sch.close()
+    assert step.snapshots == [[0], [1, 2, 3, 4], [5]]
+    assert sch.batches == [1, 4, 1] and sch.passes == 3
+    assert sch.topped_up == 2               # 3 and 4 joined [1, 2]
+
+
+def test_batch_taken_by_the_device_never_changes():
+    """What a step_fn call received stays as it was: requests chosen
+    while it runs wait for the next batch instead of joining it."""
+    step = GatedStep()
+    sch = PCScheduler(step, max_batch=8)
+    f0 = sch.submit_async(0)
+    assert step.entered.wait(10)
+    futs = publish_together(sch, [1, 2, 3])
+    assert wait_waiting(sch, 3) == [[1, 2, 3]]
+    step.release()
+    assert [f.result(timeout=10) for f in [f0] + futs] == [0, 2, 4, 6]
+    sch.close()
+    assert step.received == step.snapshots == [[0], [1, 2, 3]]
+
+
+@pytest.mark.parametrize("use_pq", [True, False])
+def test_batches_add_up_to_the_operations_served(use_pq):
+    """Closed loop: each batch is recorded once, ≤ max_batch, in the
+    order the step_fn saw them, and together they hold every operation."""
+    sizes = []
+
+    def step_fn(rows):
+        sizes.append(len(rows))
+        return [r + 1 for r in rows]
+
+    sch = PCScheduler(step_fn, max_batch=8, use_pq=use_pq)
+    n_sess, per_sess = 3 * (os.cpu_count() or 4), 20
+    answers = {}
+
+    def sess(tid):
+        answers[tid] = [sch.submit(tid * 1000 + i, deadline=float(i))
+                        for i in range(per_sess)]
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)             # interleave the threads hard
+    try:
+        ts = [threading.Thread(target=sess, args=(t,))
+              for t in range(n_sess)]
+        [t.start() for t in ts]
+        [t.join(60) for t in ts]
+    finally:
+        sys.setswitchinterval(switch)
+    assert not any(t.is_alive() for t in ts)
+    sch.close()
+    assert answers == {t: [t * 1000 + i + 1 for i in range(per_sess)]
+                       for t in range(n_sess)}
+    assert sum(sch.batches) == n_sess * per_sess
+    assert sch.batches == sizes and sch.passes == len(sizes)
+    assert all(1 <= b <= 8 for b in sch.batches)
+    assert sch.topped_up <= n_sess * per_sess
+
+
+def test_close_serves_the_waiting_batch():
+    """close() lets the device thread serve every waiting batch before
+    its sweep fails whatever is left."""
+    step = GatedStep()
+    sch = PCScheduler(step, max_batch=4)
+    f0 = sch.submit_async(0)
+    assert step.entered.wait(10)
+    futs = publish_together(sch, [1, 2, 3])
+    assert wait_waiting(sch, 3) == [[1, 2, 3]]
+    closer = threading.Thread(target=sch.close)
+    closer.start()
+    with sch._ready:                        # close() stopped the device
+        assert sch._ready.wait_for(lambda: sch._device_stop, 10)
+    step.release()
+    closer.join(10)
+    assert not closer.is_alive()
+    assert [f.result(timeout=1) for f in [f0] + futs] == [0, 2, 4, 6]
+    assert sch.batches == [1, 3] and not sch._waiting
+
+
+def test_waiting_batches_keep_the_backpressure():
+    """The combiner orders only while at most one batch waits: keys it
+    has not chosen stay in the deadline PQ, where a later, more urgent
+    request still overtakes them."""
+    step = GatedStep()
+    sch = PCScheduler(step, max_batch=2, rounds_cap=1, n_shards=2,
+                      pq_capacity=1024)
+    order, waiting_at_order = sch._order, []
+
+    def watched(new):
+        with sch._cond:
+            waiting_at_order.append(len(sch._waiting))
+        return order(new)
+
+    sch._order = watched
+    f0 = sch.submit_async(0)
+    assert step.entered.wait(10)
+    futs = publish_together(sch, [1, 2])
+    assert wait_waiting(sch, 2) == [[1, 2]]
+    # budget 2: 3 and 4 are chosen, 5..7 go to the PQ and stay there
+    futs += publish_together(sch, [3, 4, 5, 6, 7])
+    assert wait_waiting(sch, 4) == [[1, 2], [3, 4]]
+    futs += publish_together(sch, [0.5])
+    step.release()
+    assert [f.result(timeout=30) for f in [f0] + futs] == [
+        0, 2, 4, 6, 8, 10, 12, 14, 1.0]
+    sch.close()
+    assert step.received == [[0], [1, 2], [3, 4], [0.5, 5], [6, 7]]
+    assert max(waiting_at_order) <= 1
 
 
 def test_async_scheduler_propagates_step_errors():

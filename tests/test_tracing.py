@@ -15,6 +15,8 @@ from repro.core.tracing import ANCHOR, TRACER, Tracer
 from repro.launch.serve import StructureExecutor
 from repro.serving import PCScheduler
 
+from gated_step import GatedStep, publish_together, wait_waiting
+
 
 @pytest.fixture
 def stop_tracer():
@@ -158,6 +160,31 @@ def test_stage_stamps_add_up_over_a_served_map(stop_tracer):
     for name, t0, t1, _cpu in rec["spans"]:
         if name.startswith("map."):
             assert any(s <= t0 and t1 <= e for s, e, *_ in batches)
+
+
+def test_topup_counter_matches_topped_up(stop_tracer):
+    """``combiner.topup`` counts what ``PCScheduler.topped_up`` counts,
+    and an entry that joins a waiting batch keeps the stamp of the
+    ordering pass that chose it."""
+    step = GatedStep()
+    TRACER.start()
+    sch = PCScheduler(step, max_batch=4)
+    f0 = sch.submit_async(0)
+    assert step.entered.wait(10)
+    futs = publish_together(sch, [1, 2])
+    wait_waiting(sch, 2)
+    futs += publish_together(sch, [3, 4, 5])
+    assert wait_waiting(sch, 5) == [[1, 2, 3, 4], [5]]
+    with sch._cond:
+        stamps = [e.t_chosen for e in sch._waiting[0]]
+    assert 0 < stamps[0] == stamps[1] < stamps[2] == stamps[3]
+    step.release()
+    assert [f.result(timeout=10) for f in [f0] + futs] == [0, 2, 4, 6, 8,
+                                                           10]
+    sch.close()
+    rec = TRACER.stop()
+    assert rec["counters"]["combiner.topup"] == sch.topped_up == 2
+    assert [b[2] for b in rec["batches"]] == sch.batches == [1, 4, 1]
 
 
 def test_anchor_lands_in_a_cpu_profiler_trace(tmp_path):
